@@ -18,21 +18,19 @@ import sys
 
 
 def run(n_dev: int, taus, straggler: int, seed: int = 0):
-    import jax
-
     from repro.core import DMTRLConfig, MeshAxes
     from repro.core.async_dmtrl import fit_async
     from repro.core.distributed import fit_distributed
+    from repro.launch.mesh import make_mesh
     from repro.core import convergence as cv
     from repro.data.synthetic import synthetic
-
     sp = synthetic(1, m=n_dev, d=32, n_train_avg=80, n_test_avg=20, seed=2)
     delays = (1,) * (n_dev - 1) + (straggler,)
     base = dict(
         loss="hinge", lam=1e-4, outer_iters=2, rounds=8, local_iters=64,
         solver="block_gram", block_size=32, seed=seed,
     )
-    mesh = jax.make_mesh((n_dev,), ("data",))
+    mesh = make_mesh((n_dev,), ("data",))
     ax = MeshAxes(data="data")
 
     _, _, _, h_sync = fit_distributed(DMTRLConfig(**base), sp.train, mesh, ax)

@@ -57,11 +57,10 @@ def _config(tiny: bool, seed: int = 0):
 
 def run_one(transport: str, tau, n_workers: int, straggler: int,
             tiny: bool = False, seed: int = 0):
-    import jax
-
     from repro.core import AsyncOptions, MeshAxes
     from repro.core import convergence as cv
     from repro.core.async_dmtrl import fit_async
+    from repro.launch.mesh import make_mesh
 
     sp = _problem(n_workers, tiny)
     delays = (1,) * (n_workers - 1) + (straggler,)
@@ -73,7 +72,7 @@ def run_one(transport: str, tau, n_workers: int, straggler: int,
         n_workers=None if transport == "simulated" else n_workers,
     )
     mesh = (
-        jax.make_mesh((n_workers,), ("data",))
+        make_mesh((n_workers,), ("data",))
         if transport == "simulated"
         else None
     )

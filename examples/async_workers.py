@@ -29,6 +29,7 @@ from repro import obs
 from repro.core import AsyncOptions, DMTRLEstimator, MeshAxes
 from repro.core import convergence as cv
 from repro.data.synthetic import synthetic
+from repro.launch.mesh import make_mesh
 
 
 def main():
@@ -53,7 +54,7 @@ def main():
         rounds=3 if args.tiny else 8,
         local_iters=32 if args.tiny else 128, seed=0,
     )
-    mesh = jax.make_mesh((n_dev,), ("data",))
+    mesh = make_mesh((n_dev,), ("data",))
     ax = MeshAxes(data="data")
 
     print("synchronous (every round barriers on the straggler)...")

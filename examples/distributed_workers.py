@@ -15,6 +15,7 @@ import numpy as np
 
 from repro.core import DMTRLEstimator, MeshAxes
 from repro.data.synthetic import synthetic
+from repro.launch.mesh import make_mesh
 
 
 def main():
@@ -25,7 +26,7 @@ def main():
     base = dict(
         loss="hinge", lam=1e-4, outer_iters=3, rounds=8, local_iters=256, seed=0
     )
-    mesh = jax.make_mesh((min(8, n_dev),), ("data",))
+    mesh = make_mesh((min(8, n_dev),), ("data",))
     print("fitting DMTRL with tasks sharded over the 'data' axis...")
     dist = DMTRLEstimator(
         engine="distributed", mesh=mesh, axes=MeshAxes(data="data"), **base

@@ -25,11 +25,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import shard_map, shard_map_unchecked
-from . import dual as dual_mod
+from ..launch.mesh import require_auto_axes
 from . import omega as omega_mod
 from . import omega_regularizers as omega_reg
-from .dmtrl import DMTRLConfig, WarmStart, _rho_value
+from .dmtrl import DMTRLConfig, WarmStart, _rho_value, make_data_fns
 from .losses import get_loss
 from .mtl_data import MTLData
 from .sigma_view import LowRankDiagSigma, SigmaView
@@ -81,6 +80,7 @@ def shard_mtl_data(
 
     Returns (sharded data, m_padded, d_padded).
     """
+    require_auto_axes(mesh)
     dsz = _axis_size(mesh, axes.data)
     msz = _axis_size(mesh, axes.model)
     psz = _axis_size(mesh, axes.pod)
@@ -343,10 +343,14 @@ def install_initial_state(
     init,
     w_from_alpha,
 ) -> "DistributedState":
-    """Install a warm start (``init``) or a custom-init regularizer's Sigma
-    into freshly padded mesh state, rederiving W(alpha). Shared by the sync
-    and async engines so their tau=0 bit-parity anchor cannot drift."""
-    if init is None and not reg.custom_init and not reg.structured:
+    """Install a warm start (``init``), a custom-init regularizer's Sigma, or
+    the real tasks' initial Sigma when the task axis was padded, into
+    freshly padded mesh state, rederiving W(alpha). (``init_state``'s
+    I/m Sigma counts the padded tasks; the paper's init is I/m over the
+    real ones.) Shared by the sync and async engines so their tau=0
+    bit-parity anchor cannot drift."""
+    padded = m != raw.m
+    if init is None and not reg.custom_init and not reg.structured and not padded:
         return state
     if init is not None:
         if isinstance(init.sigma, SigmaView):
@@ -391,9 +395,11 @@ def round_shard_map(cfg: DMTRLConfig, axes: MeshAxes, body, mesh, in_specs, out_
     when the configured backend actually traces a pallas_call into the body
     (jax has no replication rule for pallas_call; with a model axis the
     gram path is used instead, so the check stays on)."""
-    if get_backend(cfg.solver).uses_pallas and axes.model is None:
-        return shard_map_unchecked(body, mesh, in_specs, out_specs)
-    return shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    pallas = get_backend(cfg.solver).uses_pallas and axes.model is None
+    return jax.shard_map(
+        body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=not pallas,
+    )
 
 
 def make_distributed_round(
@@ -500,7 +506,6 @@ def fit_distributed(
     if options is not None:
         cfg = options.merge_into(cfg)
     reg = omega_reg.resolve_regularizer(cfg, regularizer, m=raw.m)
-    loss = get_loss(cfg.loss)
     data, m, d = shard_mtl_data(raw, mesh, axes)
     state = init_state(data, mesh, axes, m, d)
     key = jax.random.PRNGKey(cfg.seed)
@@ -516,16 +521,7 @@ def fit_distributed(
     hist = new_event_history()
     rounds_seen = 0
 
-    @jax.jit
-    def objectives(alpha, sigma):
-        dd = dual_mod.dual_objective(data, alpha, sigma, cfg.lam, loss)
-        pp = dual_mod.primal_objective_from_alpha(data, alpha, sigma, cfg.lam, loss)
-        return dd, pp
-
-    @jax.jit
-    def w_from_alpha(alpha, sigma):
-        return dual_mod.weights_from_alpha(data, alpha, sigma, cfg.lam)
-
+    objectives, w_from_alpha = make_data_fns(cfg, data)
     state = install_initial_state(
         state, raw, data, m, cfg, mesh, axes, reg, init, w_from_alpha
     )

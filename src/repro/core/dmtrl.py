@@ -264,17 +264,18 @@ def _rho_value(
     return float(rho) * n_blocks_scale
 
 
-def make_w_step_round(cfg: DMTRLConfig, data: MTLData, rho: float):
+def make_w_step_round(cfg: DMTRLConfig, n_max: int, rho: float):
     """One communication round: local updates (vmap over tasks) + reduce.
 
-    Returns round(alpha, W, sigma, key) -> (alpha, W). jit-able.
+    Returns round(data, alpha, W, sigma, key) -> (alpha, W). jit-able; the
+    data is an argument, so a jitted round does not embed it as a constant.
     """
     loss = get_loss(cfg.loss)
     backend = get_backend(cfg.solver)
-    H = backend.round_local_iters(cfg.local_iters or data.n_max, cfg.block_size)
+    H = backend.round_local_iters(cfg.local_iters or n_max, cfg.block_size)
     solver = backend.make(loss, rho, cfg.lam, H, block=cfg.block_size)
 
-    def round_fn(alpha, W, sigma, key):
+    def round_fn(data, alpha, W, sigma, key):
         # same per-(task, pod=0) key derivation as distributed.py so the
         # single-process reference and the mesh version produce bit-equal
         # coordinate samples (tested).
@@ -301,6 +302,32 @@ def make_w_step_round(cfg: DMTRLConfig, data: MTLData, rho: float):
     return round_fn
 
 
+def make_data_fns(cfg: DMTRLConfig, data: MTLData):
+    """Jitted ``objectives(alpha, sigma) -> (dual, primal)`` and
+    ``w_from_alpha(alpha, sigma) -> W`` over ``data``.
+
+    The data enters the compiled programs as an argument: an array closed
+    over by a jitted function is baked into the program as a constant, and
+    at MNIST width ``x`` alone is 376 MB.
+    """
+    loss = get_loss(cfg.loss)
+
+    @jax.jit
+    def objectives(data, alpha, sigma):
+        dd = dual_mod.dual_objective(data, alpha, sigma, cfg.lam, loss)
+        pp = dual_mod.primal_objective_from_alpha(data, alpha, sigma, cfg.lam, loss)
+        return dd, pp
+
+    @jax.jit
+    def w_from_alpha(data, alpha, sigma):
+        return dual_mod.weights_from_alpha(data, alpha, sigma, cfg.lam)
+
+    return (
+        lambda alpha, sigma: objectives(data, alpha, sigma),
+        lambda alpha, sigma: w_from_alpha(data, alpha, sigma),
+    )
+
+
 def w_step(
     cfg: DMTRLConfig,
     data: MTLData,
@@ -312,21 +339,15 @@ def w_step(
     track: bool = True,
 ) -> tuple[Array, Array, Dict[str, np.ndarray]]:
     """Run cfg.rounds communication rounds; returns updated alpha, W, history."""
-    loss = get_loss(cfg.loss)
-    round_fn = jax.jit(make_w_step_round(cfg, data, rho))
-
-    @jax.jit
-    def objectives(alpha):
-        d = dual_mod.dual_objective(data, alpha, sigma, cfg.lam, loss)
-        p = dual_mod.primal_objective_from_alpha(data, alpha, sigma, cfg.lam, loss)
-        return d, p
+    round_fn = jax.jit(make_w_step_round(cfg, data.n_max, rho))
+    objectives, _ = make_data_fns(cfg, data)
 
     hist = {"round": [], "dual": [], "primal": [], "gap": []}
     keys = jax.random.split(key, cfg.rounds)
     for t in range(cfg.rounds):
-        alpha, W = round_fn(alpha, W, sigma, keys[t])
+        alpha, W = round_fn(data, alpha, W, sigma, keys[t])
         if track and (t % cfg.track_every == 0 or t == cfg.rounds - 1):
-            d, p = objectives(alpha)
+            d, p = objectives(alpha, sigma)
             hist["round"].append(t + 1)
             hist["dual"].append(float(d))
             hist["primal"].append(float(p))

@@ -22,7 +22,6 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Callable, Dict, List, Optional
 
-import jax
 import numpy as np
 
 from .async_dmtrl import AsyncOptions, fit_async as _fit_async
@@ -34,6 +33,7 @@ from .distributed import (
 from .dmtrl import DMTRLConfig, WarmStart, fit as _fit_reference
 from .mtl_data import MTLData
 from .sigma_view import SigmaView, maybe_dense
+from ..launch.mesh import make_mesh
 from ..obs.trace import span
 
 
@@ -88,7 +88,7 @@ def available_engines() -> Dict[str, Engine]:
 
 def _default_mesh(axes: MeshAxes):
     """A 1-device mesh so mesh engines stay usable without ceremony."""
-    return jax.make_mesh((1,), (axes.data,))
+    return make_mesh((1,), (axes.data,))
 
 
 def _unpad_state(state, raw: MTLData) -> tuple:

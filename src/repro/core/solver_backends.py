@@ -25,9 +25,11 @@ Registered backends:
                pallas_call, ``w``/``r`` VMEM-resident across blocks,
                coordinate sampling on-device (docs/DESIGN.md §6).
 
-Pallas backends fall back to their jnp reference for losses without a
-closed-form kernel delta (see ``kernels.sdca.SUPPORTED_LOSSES``), so every
-backend is total over the loss registry.
+The Pallas backends cover only the losses with a closed-form kernel delta
+(``kernels.sdca.SUPPORTED_LOSSES``) and refuse any other loss when they are
+built; ``pallas_round`` also refuses a task block over its VMEM budget
+(``kernels.sdca.sdca_kernel.ROUND_VMEM_BUDGET``) when it is traced. Neither
+runs a jnp path in the kernel's place.
 """
 from __future__ import annotations
 
@@ -66,7 +68,7 @@ class SolverBackend:
     # pallas_call launches per local round for given (H, block)
     pallas_calls: Callable[[int, int], int] = lambda H, block: 0
     # solve body contains pallas_call ops: shard_map engines must disable
-    # replication checking around it (compat.shard_map_unchecked)
+    # replication checking around it (distributed.round_shard_map)
     uses_pallas: bool = False
 
     def round_local_iters(self, H: int, block: int) -> int:
@@ -98,6 +100,17 @@ def get_backend(name: str) -> SolverBackend:
 
 def available_backends() -> Dict[str, SolverBackend]:
     return dict(sorted(_REGISTRY.items()))
+
+
+def _check_kernel_loss(backend: str, loss: Loss) -> None:
+    from repro.kernels.sdca import SUPPORTED_LOSSES  # lazy: kernel layer
+
+    if loss.name not in SUPPORTED_LOSSES:
+        raise ValueError(
+            f"the {backend} backend has no kernel delta for loss "
+            f"{loss.name!r} (kernel losses: {SUPPORTED_LOSSES}); use "
+            f'solver="block_gram"'
+        )
 
 
 def _kappa(rho: float, lam: float, n_i: Array, sigma_ii: Array, dtype) -> Array:
@@ -162,6 +175,7 @@ def _make_pallas_block(
             "the pallas_block backend computes its own d-contractions; with "
             "a sharded feature dim use block_gram (psum'ed) instead"
         )
+    _check_kernel_loss("pallas_block", loss)
     from repro.kernels.sdca import ops as sdca_ops  # lazy: kernel layer
 
     def solve(x, y, alpha_i, w_i, n_i, sigma_ii, key):
@@ -203,6 +217,7 @@ def _make_pallas_round(
             "the pallas_round backend computes its own d-contractions; with "
             "a sharded feature dim use block_gram (psum'ed) instead"
         )
+    _check_kernel_loss("pallas_round", loss)
     from repro.kernels.sdca import ops as sdca_ops  # lazy: kernel layer
 
     def solve(x, y, alpha_i, w_i, n_i, sigma_ii, key):

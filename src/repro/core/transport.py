@@ -97,7 +97,6 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from . import convergence as conv_mod
-from . import dual as dual_mod
 from . import omega as omega_mod
 from .distributed import (
     DistributedState,
@@ -115,7 +114,7 @@ from .distributed import (
     server_reduce,
     shard_mtl_data,
 )
-from .dmtrl import DMTRLConfig
+from .dmtrl import DMTRLConfig, make_data_fns
 from .losses import get_loss
 from .sigma_view import SigmaView, maybe_dense
 from .solver_backends import get_backend
@@ -672,7 +671,6 @@ class SimulatedTransport(Transport):
             )
         self.cfg, self.raw, self.mesh, self.axes = cfg, raw, mesh, axes
         self.reg, self.track = reg, track
-        loss = get_loss(cfg.loss)
         data, m, d = shard_mtl_data(raw, mesh, axes)
         self.data, self.m, self.d = data, m, d
         self.state = init_state(data, mesh, axes, m, d)
@@ -684,18 +682,7 @@ class SimulatedTransport(Transport):
         self._sr = NamedSharding(mesh, P(axes.data, None))
         self.hist = new_event_history()
 
-        @jax.jit
-        def objectives(alpha, sigma):
-            dd = dual_mod.dual_objective(data, alpha, sigma, cfg.lam, loss)
-            pp = dual_mod.primal_objective_from_alpha(
-                data, alpha, sigma, cfg.lam, loss
-            )
-            return dd, pp
-
-        @jax.jit
-        def w_from_alpha(alpha, sigma):
-            return dual_mod.weights_from_alpha(data, alpha, sigma, cfg.lam)
-
+        objectives, w_from_alpha = make_data_fns(cfg, data)
         self._objectives = objectives
         self._w_from_alpha = w_from_alpha
         self.state = install_initial_state(
@@ -1005,20 +992,7 @@ class _HostServerTransport(Transport):
         self.pace = 0.0 if cfg.async_delays is None else PACE_SECONDS
         self.R = cfg.rounds
         data, dtype = self.data, self.data.x.dtype
-        loss = get_loss(cfg.loss)
-
-        @jax.jit
-        def objectives(alpha, sigma):
-            dd = dual_mod.dual_objective(data, alpha, sigma, cfg.lam, loss)
-            pp = dual_mod.primal_objective_from_alpha(
-                data, alpha, sigma, cfg.lam, loss
-            )
-            return dd, pp
-
-        @jax.jit
-        def w_from_alpha(alpha, sigma):
-            return dual_mod.weights_from_alpha(data, alpha, sigma, cfg.lam)
-
+        objectives, w_from_alpha = make_data_fns(cfg, data)
         self._objectives = objectives
         self._w_from_alpha = w_from_alpha
 
@@ -1470,6 +1444,17 @@ class MultiprocessTransport(_HostServerTransport):
     is not an authentication boundary."""
 
     name = "multiprocess"
+
+    def __init__(self):
+        # the workers are CPU processes; under a parent on an accelerator
+        # their solves would silently leave the device for the host
+        if jax.default_backend() != "cpu":
+            raise RuntimeError(
+                "the multiprocess transport is a loopback shim whose workers "
+                f"run on the CPU; the parent runs on {jax.default_backend()!r}. "
+                "Use transport='simulated' on an accelerator"
+            )
+        super().__init__()
 
     def setup(self, cfg, raw, *, mesh, axes, reg, init, track):
         super().setup(cfg, raw, mesh=mesh, axes=axes, reg=reg, init=init, track=track)

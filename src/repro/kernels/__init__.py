@@ -1,3 +1,9 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels for the compute hot-spots (SDCA local round; flash
+attention and SSD for the model zoo)."""
+import jax
+
+
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run in the interpreter: only on the CPU, which
+    has no Mosaic compiler. On a TPU every kernel compiles."""
+    return jax.default_backend() == "cpu"

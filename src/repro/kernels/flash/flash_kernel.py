@@ -89,7 +89,8 @@ def flash_attention(
     window: int = 0,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> Array:
     B, H, S, HD = q.shape
     Sk = k.shape[2]

@@ -1,15 +1,13 @@
 """jit'd wrapper: layout adaptation (B,S,H,HD) <-> (B,H,S,HD) + padding."""
 from __future__ import annotations
 
-import os
-
 import jax
 import jax.numpy as jnp
 
+from .. import interpret_mode
 from .flash_kernel import flash_attention
 
 Array = jax.Array
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
 
 
 def flash_attention_bshd(
@@ -39,7 +37,7 @@ def flash_attention_bshd(
         # decoder use; for non-causal padding would need an explicit mask.
         assert causal, "non-causal padding unsupported; pre-pad inputs"
     out = flash_attention(
-        qt, kt, vt, causal, window, bq, bk, interpret=INTERPRET
+        qt, kt, vt, causal, window, bq, bk, interpret=interpret_mode()
     )
     out = out[:, :, :S] if pad_q else out
     return jnp.moveaxis(out, 1, 2)

@@ -7,22 +7,19 @@ Used by the solver-backend registry (repro.core.solver_backends):
   * ``sdca_round``        — one fused local round (all H/B blocks in a
     single pallas_call); backs the ``pallas_round`` backend.
 
-Losses outside ``SUPPORTED_LOSSES`` (no closed-form delta in the kernel)
-fall back to the pure-jnp reference with identical iterate semantics.
+Both compile for the TPU and interpret only on the CPU
+(``repro.kernels.interpret_mode``). Losses outside ``SUPPORTED_LOSSES`` (no
+closed-form delta in the kernel) raise; the backends refuse them when they
+are built.
 """
 from __future__ import annotations
 
-import os
-
 import jax
 
-from .ref import sdca_block_ref, sdca_round_ref
-from .sdca_kernel import SUPPORTED_LOSSES, sdca_block_kernel, sdca_round_kernel
+from .. import interpret_mode
+from .sdca_kernel import sdca_block_kernel, sdca_round_kernel
 
 Array = jax.Array
-
-# interpret=True on CPU (this container); on TPU set REPRO_PALLAS_INTERPRET=0
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
 
 
 def sdca_block_apply(
@@ -36,11 +33,9 @@ def sdca_block_apply(
     loss_name: str,
 ) -> Array:
     """Deltas for ONE block; the caller scatters them and updates r."""
-    if loss_name in SUPPORTED_LOSSES:
-        return sdca_block_kernel(
-            xb, w, r, at0, y, cb, kappa, loss_name, interpret=INTERPRET
-        )
-    return sdca_block_ref(xb, w, r, at0, y, cb, kappa, loss_name)
+    return sdca_block_kernel(
+        xb, w, r, at0, y, cb, kappa, loss_name, interpret=interpret_mode()
+    )
 
 
 def sdca_round(
@@ -55,9 +50,7 @@ def sdca_round(
     block: int = 64,
 ):
     """(dalpha, r) for one fused local round (single pallas_call)."""
-    if loss_name in SUPPORTED_LOSSES:
-        return sdca_round_kernel(
-            x, y, alpha_i, w, u, n_i, kappa, loss_name,
-            block=block, interpret=INTERPRET,
-        )
-    return sdca_round_ref(x, y, alpha_i, w, u, n_i, kappa, loss_name)
+    return sdca_round_kernel(
+        x, y, alpha_i, w, u, n_i, kappa, loss_name,
+        interpret=interpret_mode(), block=block,
+    )

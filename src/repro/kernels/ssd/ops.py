@@ -4,16 +4,15 @@ Drop-in equivalent of models/ssm.ssd_chunked (tested against it and the
 naive recurrence)."""
 from __future__ import annotations
 
-import os
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 
+from .. import interpret_mode
 from .ssd_kernel import ssd_chunk_kernel
 
 Array = jax.Array
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
 
 
 def ssd_forward(
@@ -43,7 +42,7 @@ def ssd_forward(
     Cc = to_chunks(Cm)
 
     Y_intra, S_local, a_tot = ssd_chunk_kernel(
-        xc, dtc, A, Bc, Cc, interpret=INTERPRET
+        xc, dtc, A, Bc, Cc, interpret=interpret_mode()
     )
 
     # inter-chunk: associative scan over (a_tot, S_local) along chunk axis

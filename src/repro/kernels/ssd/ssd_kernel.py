@@ -73,7 +73,8 @@ def ssd_chunk_kernel(
     A: Array,  # (H,)
     Bm: Array,  # (B, H, nc, Q, N)
     Cm: Array,  # (B, H, nc, Q, N)
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ):
     B, H, nc, Q, P = x.shape
     N = Bm.shape[-1]

@@ -1,20 +1,20 @@
 import os
+from pathlib import Path
 
 import jax
 import pytest
 
 # persistent XLA compilation cache: the suite is compile-dominated on CPU,
-# so re-runs (local dev, cached CI) skip most of the wall clock. Opt out
-# with JAX_COMPILATION_CACHE_DIR="" in the environment.
-_cache_dir = os.environ.get(
-    "JAX_COMPILATION_CACHE_DIR", "/tmp/jax-cache-dmtrl-repro"
+# so re-runs skip most of the wall clock. JAX_COMPILATION_CACHE_DIR wins
+# when it is set (an empty value turns the cache off); otherwise the cache
+# lives at a fixed path inside the checkout. The environment carries it to
+# the subprocess-based mesh tests.
+os.environ.setdefault(
+    "JAX_COMPILATION_CACHE_DIR", str(Path(__file__).resolve().parents[1] / ".jax_cache")
 )
-if _cache_dir:
-    jax.config.update("jax_compilation_cache_dir", _cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    # subprocess-based mesh tests pick the cache up from the environment
-    os.environ["JAX_COMPILATION_CACHE_DIR"] = _cache_dir
-    os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0.5")
+os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0.5")
+jax.config.update("jax_compilation_cache_dir", os.environ["JAX_COMPILATION_CACHE_DIR"])
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
 
 
 def pytest_configure(config):
@@ -68,4 +68,6 @@ def small_cfg():
 
 @pytest.fixture(scope="session")
 def one_device_mesh():
-    return jax.make_mesh((1,), ("data",))
+    from repro.launch.mesh import make_mesh
+
+    return make_mesh((1,), ("data",))

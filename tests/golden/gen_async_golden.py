@@ -68,14 +68,14 @@ _RUNNER = textwrap.dedent(
     import jax, numpy as np
     sys.path.insert(0, {repo!r} + "/src")
     from repro.core import DMTRLConfig, MeshAxes
+    from repro.launch.mesh import make_mesh
     from repro.core.async_dmtrl import fit_async
     from repro.data.synthetic import synthetic
-
     prob = {prob!r}
     cfg_kw = {cfg!r}
     cfg_kw["async_delays"] = tuple(cfg_kw["async_delays"])
     sp = synthetic(1, **prob)
-    mesh = jax.make_mesh(({devices},), ("data",))
+    mesh = make_mesh(({devices},), ("data",))
     _, _, _, hist = fit_async(
         DMTRLConfig(**cfg_kw), sp.train, mesh, MeshAxes(data="data")
     )

@@ -65,12 +65,13 @@ _STRAGGLER_SUBPROC = textwrap.dedent(
     import jax, numpy as np
     sys.path.insert(0, {repo!r} + "/src")
     from repro.core import DMTRLConfig, MeshAxes, fit_async, fit_distributed
+    from repro.launch.mesh import make_mesh
     from repro.data.synthetic import synthetic
 
     sp = synthetic(1, m=4, d=16, n_train_avg=40, n_test_avg=10, seed=3)
     base = dict(loss="hinge", lam=1e-3, outer_iters=1, rounds=4,
                 local_iters=32, solver="block_gram", block_size=32, seed=0)
-    mesh = jax.make_mesh((4,), ("data",))
+    mesh = make_mesh((4,), ("data",))
     ax = MeshAxes(data="data")
     _, _, _, h_sync = fit_distributed(DMTRLConfig(**base), sp.train, mesh, ax)
     out = dict(sync_gap=float(h_sync["gap"][-1]))
@@ -276,12 +277,13 @@ _SUBPROC = textwrap.dedent(
     import jax, numpy as np
     sys.path.insert(0, {repo!r} + "/src")
     from repro.core import DMTRLConfig, MeshAxes, fit_async, fit_distributed
+    from repro.launch.mesh import make_mesh
     from repro.data.synthetic import synthetic
 
     sp = synthetic(1, m=8, d=24, n_train_avg=50, n_test_avg=10, seed=2)
     base = dict(loss="hinge", lam=1e-3, outer_iters=2, rounds=4,
                 local_iters=32, solver="block_gram", block_size=32, seed=0)
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     ax = MeshAxes(data="data")
     cfg = DMTRLConfig(**base)
     W1, s1, st1, h1 = fit_distributed(cfg, sp.train, mesh, ax)

@@ -29,6 +29,21 @@ def test_one_device_mesh_equals_reference(
     np.testing.assert_allclose(sigma, np.asarray(res.sigma), atol=1e-5)
 
 
+@pytest.mark.parametrize("engine", ["distributed", "async"])
+def test_explicit_mesh_is_rejected(small_problem, small_cfg, engine):
+    """jax.make_mesh's default Explicit axes break the engines' host-side
+    Sigma algebra; both mesh engines refuse such a mesh by name."""
+    import jax
+    from jax.sharding import AxisType
+
+    from repro.core import DMTRLEstimator
+
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Explicit,))
+    est = DMTRLEstimator(engine=engine, mesh=mesh, config=small_cfg)
+    with pytest.raises(ValueError, match=r"Auto-typed .*'data': 'Explicit'"):
+        est.fit(small_problem.train)
+
+
 _SUBPROC = textwrap.dedent(
     """
     import os
@@ -37,14 +52,15 @@ _SUBPROC = textwrap.dedent(
     import jax, numpy as np
     sys.path.insert(0, {repo!r} + "/src")
     from repro.core import DMTRLConfig, MeshAxes, fit, fit_distributed
+    from repro.launch.mesh import make_mesh
     from repro.data.synthetic import synthetic
 
-    sp = synthetic(1, m=8, d=32, n_train_avg=70, n_test_avg=20, seed=2)
+    sp = synthetic(1, m={m}, d=32, n_train_avg=70, n_test_avg=20, seed=2)
     cfg = DMTRLConfig(loss={loss!r}, lam=1e-3, outer_iters=2, rounds=3,
                       local_iters=64, solver="block_gram", block_size=32, seed=0,
                       **{extra})
     res = fit(cfg, sp.train)
-    mesh = jax.make_mesh({mesh_shape}, {mesh_axes})
+    mesh = make_mesh({mesh_shape}, {mesh_axes})
     W, sigma, _, hist = fit_distributed(cfg, sp.train, mesh, MeshAxes(**{axes_kw}))
     werr = float(np.max(np.abs(W - np.asarray(res.W))))
     serr = float(np.max(np.abs(sigma - np.asarray(res.sigma))))
@@ -55,10 +71,10 @@ _SUBPROC = textwrap.dedent(
 )
 
 
-def _run_subproc(loss, mesh_shape, mesh_axes, axes_kw, extra="dict()"):
+def _run_subproc(loss, mesh_shape, mesh_axes, axes_kw, extra="dict()", m=8):
     code = _SUBPROC.format(
         repo=REPO, loss=loss, mesh_shape=mesh_shape, mesh_axes=mesh_axes,
-        axes_kw=axes_kw, extra=extra,
+        axes_kw=axes_kw, extra=extra, m=m,
     )
     out = subprocess.run(
         [sys.executable, "-c", code],
@@ -75,6 +91,15 @@ def _run_subproc(loss, mesh_shape, mesh_axes, axes_kw, extra="dict()"):
 def test_eight_workers_data_parallel_exact():
     """8 tasks over 8 workers — the paper's one-task-per-worker setting."""
     r = _run_subproc("hinge", "(8,)", '("data",)', 'dict(data="data")')
+    assert r["werr"] < 5e-4, r
+    assert r["serr"] < 5e-5, r
+
+
+def test_padded_task_axis_matches_reference():
+    """6 tasks over 4 workers pad the task axis to 8. The real tasks must
+    start from the paper's Sigma = I/6, not I/8 over the padded count (the
+    MNIST shape on four chips pads 10 tasks to 12)."""
+    r = _run_subproc("hinge", "(4,)", '("data",)', 'dict(data="data")', m=6)
     assert r["werr"] < 5e-4, r
     assert r["serr"] < 5e-5, r
 

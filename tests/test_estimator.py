@@ -126,12 +126,13 @@ _PARITY_SUBPROC = textwrap.dedent(
     import jax, numpy as np
     sys.path.insert(0, {repo!r} + "/src")
     from repro.core import AsyncOptions, DMTRLConfig, DMTRLEstimator, MeshAxes
+    from repro.launch.mesh import make_mesh
     from repro.data.synthetic import synthetic
 
     sp = synthetic(1, m=8, d=32, n_train_avg=70, n_test_avg=20, seed=2)
     cfg = DMTRLConfig(loss="hinge", lam=1e-3, outer_iters=2, rounds=3,
                       local_iters=64, solver="block_gram", block_size=32, seed=0)
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     ax = MeshAxes(data="data")
     ref = DMTRLEstimator(engine="reference", config=cfg).fit(sp.train)
     dist = DMTRLEstimator(engine="distributed", config=cfg, mesh=mesh,

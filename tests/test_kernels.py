@@ -1,9 +1,11 @@
-"""Per-kernel shape/dtype sweeps vs the pure-jnp oracles (interpret mode)."""
+"""Per-kernel shape/dtype sweeps vs the pure-jnp oracles (interpreted on
+the CPU, compiled on a TPU)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels import interpret_mode
 from repro.kernels.flash import flash_attention
 from repro.kernels.flash.ops import flash_attention_bshd
 from repro.kernels.flash.ref import attention_ref
@@ -37,7 +39,10 @@ def test_flash_vs_ref(B, H, S, HD, causal, window, dtype):
     q = jax.random.normal(ks[0], (B, H, S, HD), dtype)
     k = jax.random.normal(ks[1], (B, H, S, HD), dtype)
     v = jax.random.normal(ks[2], (B, H, S, HD), dtype)
-    out = flash_attention(q, k, v, causal, window, block_q=64, block_k=64)
+    out = flash_attention(
+        q, k, v, causal, window, block_q=64, block_k=64,
+        interpret=interpret_mode(),
+    )
     ref = attention_ref(q, k, v, causal, window)
     tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
     np.testing.assert_allclose(
@@ -92,7 +97,9 @@ def test_sdca_kernel_vs_ref(loss, B, d):
     )
     cb = jax.random.randint(ks[0], (B,), 0, max(B // 2, 1))  # force duplicates
     kappa = jnp.float32(0.9)
-    dk = sdca_block_kernel(xb, w, r, at0, y, cb, kappa, loss, d_tile=256)
+    dk = sdca_block_kernel(
+        xb, w, r, at0, y, cb, kappa, loss, interpret=interpret_mode(), d_tile=256
+    )
     dr = sdca_block_ref(xb, w, r, at0, y, cb, kappa, loss)
     np.testing.assert_allclose(np.asarray(dk), np.asarray(dr), atol=5e-6)
 
@@ -126,7 +133,9 @@ def test_sdca_round_kernel_vs_ref(loss, n, d, H, block):
     u = jax.random.uniform(ks[4], (H,))
     n_i = jnp.int32(max(n - 7, 1))  # padded tail + duplicate draws
     kappa = jnp.float32(0.9)
-    dak, rk = sdca_round_kernel(x, y, alpha, w, u, n_i, kappa, loss, block=block)
+    dak, rk = sdca_round_kernel(
+        x, y, alpha, w, u, n_i, kappa, loss, interpret=interpret_mode(), block=block
+    )
     dar, rr = sdca_round_ref(x, y, alpha, w, u, n_i, kappa, loss)
     np.testing.assert_allclose(np.asarray(dak), np.asarray(dar), atol=1e-5)
     np.testing.assert_allclose(np.asarray(rk), np.asarray(rr), atol=1e-5)
@@ -168,7 +177,7 @@ def test_ssd_chunk_kernel_matches_chunk_ref():
     A = -jnp.exp(jax.random.normal(ks[3], (H,)))
     Bm = jax.random.normal(ks[4], (B, H, nc, Q, N)) * 0.3
     Cm = jax.random.normal(ks[5], (B, H, nc, Q, N)) * 0.3
-    Yk, Sk, ak = ssd_chunk_kernel(x, dt, A, Bm, Cm)
+    Yk, Sk, ak = ssd_chunk_kernel(x, dt, A, Bm, Cm, interpret=interpret_mode())
     Yr, Sr, ar = chunk_ref(x, dt, A, Bm, Cm)
     np.testing.assert_allclose(np.asarray(Yk), np.asarray(Yr), atol=1e-5)
     # kernel S is (N, P); ref is (N, P) too via einsum 'bhcqn,bhcqp->bhcnp'

@@ -84,14 +84,14 @@ def test_w_step_round_monotone_dual_ascent(data, loss_name):
     loss = get_loss(loss_name)
     sigma, _ = om.init_sigma(data.m)
     rho = float(om.rho_lemma10(sigma))
-    round_fn = make_w_step_round(cfg, data, rho)
+    round_fn = make_w_step_round(cfg, data.n_max, rho)
     alpha = jnp.zeros((data.m, data.n_max))
     W = jnp.zeros((data.m, data.d))
     prev = float(dm.dual_objective(data, alpha, sigma, cfg.lam, loss))
     key = jax.random.PRNGKey(17)
     for t in range(6):
         key, sub = jax.random.split(key)
-        alpha, W = round_fn(alpha, W, sigma, sub)
+        alpha, W = round_fn(data, alpha, W, sigma, sub)
         cur = float(dm.dual_objective(data, alpha, sigma, cfg.lam, loss))
         assert cur >= prev - 1e-4, (loss_name, t, prev, cur)
         prev = cur
@@ -101,12 +101,12 @@ def test_w_invariant_after_rounds(data):
     """Carried W must equal W(alpha) after any number of rounds."""
     cfg = DMTRLConfig(loss="hinge", lam=1e-3, local_iters=64)
     sigma, _ = om.init_sigma(data.m)
-    round_fn = make_w_step_round(cfg, data, 1.0)
+    round_fn = make_w_step_round(cfg, data.n_max, 1.0)
     alpha = jnp.zeros((data.m, data.n_max))
     W = jnp.zeros((data.m, data.d))
     key = jax.random.PRNGKey(23)
     for _ in range(3):
         key, sub = jax.random.split(key)
-        alpha, W = round_fn(alpha, W, sigma, sub)
+        alpha, W = round_fn(data, alpha, W, sigma, sub)
     W2 = dm.weights_from_alpha(data, alpha, sigma, cfg.lam)
     np.testing.assert_allclose(np.asarray(W), np.asarray(W2), atol=1e-4)

@@ -5,8 +5,12 @@ for one (key, shape, loss) triple every backend walks the SAME sampled
 coordinate order and must produce the same iterate sequence:
 
   * naive / pallas_block vs block_gram: equal up to float-op reordering.
-  * pallas_round vs block_gram: BIT-equal in interpret mode (the fused
-    kernel replays the block-Gram recursion op for op, acceptance anchor).
+  * pallas_round vs block_gram: equal to float32 rounding (``ROUND_ATOL``).
+    The fused kernel runs the block-Gram recursion step for step, but it
+    reads lane k of a vector as a masked lane sum and reduces
+    ``G[k, :] . deltas`` across lanes, the layout the TPU compiler accepts,
+    so its sums associate differently from XLA's dot and the last bits of
+    an iterate can differ (measured: <= 3e-8 on these problems).
 
 hypothesis is an optional test dependency (see pyproject's [test] extra);
 the property sweep imports it via ``pytest.importorskip`` at call time so a
@@ -25,6 +29,7 @@ from repro.core.solver_backends import (
 
 KERNEL_LOSSES = ("hinge", "squared", "smoothed_hinge")
 BACKENDS = ("naive", "block_gram", "pallas_block", "pallas_round")
+ROUND_ATOL = 1e-6  # float32 rounding over <= 96 coordinate steps of O(1) values
 
 
 def _problem(seed, n, d, n_valid):
@@ -37,11 +42,11 @@ def _problem(seed, n, d, n_valid):
     return x, y, alpha, w, jnp.int32(n_valid), jnp.float32(0.25), ks[0]
 
 
-def _run_all(loss_name, seed, n, d, n_valid, H, block):
+def _run_all(loss_name, seed, n, d, n_valid, H, block, backends=BACKENDS):
     loss = get_loss(loss_name)
     args = _problem(seed, n, d, n_valid)
     out = {}
-    for name in BACKENDS:
+    for name in backends:
         be = get_backend(name)
         solve = be.make(loss, 2.0, 1e-3, be.round_local_iters(H, block), block=block)
         da, r = solve(*args)
@@ -57,20 +62,40 @@ def test_all_backends_same_iterates(loss_name, n, d, H, block):
     for name in ("naive", "pallas_block"):
         np.testing.assert_allclose(out[name][0], da0, atol=2e-5, err_msg=name)
         np.testing.assert_allclose(out[name][1], r0, atol=2e-5, err_msg=name)
-    # acceptance anchor: the fused round kernel replays block_gram bit-exactly
-    np.testing.assert_array_equal(out["pallas_round"][0], da0)
-    np.testing.assert_array_equal(out["pallas_round"][1], r0)
+    # the fused round kernel replays block_gram to float32 rounding
+    np.testing.assert_allclose(out["pallas_round"][0], da0, rtol=0, atol=ROUND_ATOL)
+    np.testing.assert_allclose(out["pallas_round"][1], r0, rtol=0, atol=ROUND_ATOL)
 
 
 @pytest.mark.parametrize("loss_name", ["logistic", "eps_insensitive"])
 def test_kernel_fallback_losses_still_parity(loss_name):
-    """Losses without a closed-form kernel delta fall back to references
-    with the same iterate semantics (not bit-equal: different float path)."""
-    out = _run_all(loss_name, seed=3, n=48, d=20, n_valid=48, H=64, block=32)
-    da0, r0 = out["block_gram"]
+    """Losses without a closed-form kernel delta have no Pallas path: the
+    Pallas backends refuse them when built instead of running a jnp
+    fallback, and the jnp backends still agree on them."""
+    loss = get_loss(loss_name)
     for name in ("pallas_block", "pallas_round"):
-        np.testing.assert_allclose(out[name][0], da0, atol=2e-5, err_msg=name)
-        np.testing.assert_allclose(out[name][1], r0, atol=2e-5, err_msg=name)
+        with pytest.raises(ValueError, match="no kernel delta"):
+            get_backend(name).make(loss, 2.0, 1e-3, 64, block=32)
+    out = _run_all(
+        loss_name, seed=3, n=48, d=20, n_valid=48, H=64, block=32,
+        backends=("naive", "block_gram"),
+    )
+    np.testing.assert_allclose(out["naive"][0], out["block_gram"][0], atol=2e-5)
+    np.testing.assert_allclose(out["naive"][1], out["block_gram"][1], atol=2e-5)
+
+
+def test_pallas_round_refuses_task_block_over_vmem_budget():
+    """A task block that cannot stay in VMEM (MNIST width: 12000 x 784) is
+    refused with its numbers when traced; nothing else runs in its place."""
+    be = get_backend("pallas_round")
+    solve = be.make(get_loss("hinge"), 2.0, 1e-3, be.round_local_iters(12000, 64))
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
+    args = (
+        f32(12000, 784), f32(12000), f32(12000), f32(784),
+        jax.ShapeDtypeStruct((), jnp.int32), f32(), jax.random.PRNGKey(0),
+    )
+    with pytest.raises(ValueError, match=r"n_max=12000 x d=784 .* budget"):
+        jax.eval_shape(solve, *args)
 
 
 def test_backend_parity_property():
@@ -98,8 +123,12 @@ def test_backend_parity_property():
         for name in ("naive", "pallas_block"):
             np.testing.assert_allclose(out[name][0], da0, atol=5e-5)
             np.testing.assert_allclose(out[name][1], r0, atol=5e-5)
-        np.testing.assert_array_equal(out["pallas_round"][0], da0)
-        np.testing.assert_array_equal(out["pallas_round"][1], r0)
+        np.testing.assert_allclose(
+            out["pallas_round"][0], da0, rtol=0, atol=ROUND_ATOL
+        )
+        np.testing.assert_allclose(
+            out["pallas_round"][1], r0, rtol=0, atol=ROUND_ATOL
+        )
 
     check()
 
@@ -130,7 +159,7 @@ def test_pallas_backends_reject_sharded_features():
 def test_mesh_engines_run_pallas_backends(one_device_mesh):
     """fit_distributed and fit_async must trace pallas backends under
     shard_map (replication checking has no pallas_call rule — the round
-    builder must route through compat.shard_map_unchecked) and keep the
+    builder must route through distributed.round_shard_map) and keep the
     tau=0 bit-parity anchor."""
     from repro.core import DMTRLConfig, MeshAxes, fit_async, fit_distributed
     from repro.data.synthetic import synthetic
@@ -152,9 +181,8 @@ def test_mesh_engines_run_pallas_backends(one_device_mesh):
 def test_engine_fit_runs_on_every_backend():
     """The whole Algorithm-1 driver works with each registered backend.
 
-    (Bit-equality of pallas_round vs block_gram is asserted per task above;
-    under the engine's vmap+jit XLA batches the jnp matmuls differently, so
-    across a full fit the runs agree only to float tolerance.)"""
+    (pallas_round vs block_gram is asserted per task above; across a full
+    fit the runs agree to float tolerance.)"""
     from repro.core import DMTRLConfig, fit
     from repro.data.synthetic import synthetic
 

@@ -132,13 +132,13 @@ _GOLDEN_SUBPROC = textwrap.dedent(
     import jax, numpy as np
     sys.path.insert(0, {repo!r} + "/src")
     from repro.core import DMTRLConfig, MeshAxes
+    from repro.launch.mesh import make_mesh
     from repro.core.async_dmtrl import fit_async
     from repro.data.synthetic import synthetic
-
     rec = json.loads({rec!r})
     cfg_kw = dict(rec["config"]); cfg_kw["async_delays"] = tuple(cfg_kw["async_delays"])
     sp = synthetic(1, **rec["problem"])
-    mesh = jax.make_mesh(({devices},), ("data",))
+    mesh = make_mesh(({devices},), ("data",))
     _, _, _, hist = fit_async(
         DMTRLConfig(**cfg_kw), sp.train, mesh, MeshAxes(data="data")
     )
@@ -493,6 +493,18 @@ def test_multiprocess_ssp_straggler(small_problem, small_cfg):
     )
     assert h1["w_lag"].max() <= 1
     assert float(h1["gap"][-1]) <= 2.0 * abs(float(h0["gap"][-1])) + 1e-9
+
+
+def test_multiprocess_refuses_parent_off_the_cpu(
+    small_problem, small_cfg, monkeypatch
+):
+    """Its workers are CPU processes: under a parent that holds an
+    accelerator the transport refuses before starting any of them."""
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="runs on 'tpu'"):
+        _fit_transport(small_cfg, small_problem.train, "multiprocess", 2, tau=0)
 
 
 # ---------------------------------------------------------------------------
