@@ -26,6 +26,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..launch.mesh import require_auto_axes
+from ..obs.trace import span
 from . import omega as omega_mod
 from . import omega_regularizers as omega_reg
 from .dmtrl import DMTRLConfig, WarmStart, _rho_value, make_data_fns
@@ -506,8 +507,16 @@ def fit_distributed(
     if options is not None:
         cfg = options.merge_into(cfg)
     reg = omega_reg.resolve_regularizer(cfg, regularizer, m=raw.m)
-    data, m, d = shard_mtl_data(raw, mesh, axes)
-    state = init_state(data, mesh, axes, m, d)
+    # driver spans (obs): shard / rho / round / objectives / omega_step /
+    # result, on the profiler's clock while a profiler session records.
+    # They add no host sync: each readback below was there before.
+    with span("shard", cat="driver"):
+        data, m, d = shard_mtl_data(raw, mesh, axes)
+        state = init_state(data, mesh, axes, m, d)
+        objectives, w_from_alpha = make_data_fns(cfg, data)
+        state = install_initial_state(
+            state, raw, data, m, cfg, mesh, axes, reg, init, w_from_alpha
+        )
     key = jax.random.PRNGKey(cfg.seed)
 
     # the synchronous engine IS the degenerate tau=0 transport: every round
@@ -521,13 +530,11 @@ def fit_distributed(
     hist = new_event_history()
     rounds_seen = 0
 
-    objectives, w_from_alpha = make_data_fns(cfg, data)
-    state = install_initial_state(
-        state, raw, data, m, cfg, mesh, axes, reg, init, w_from_alpha
-    )
-
     for p in range(cfg.outer_iters):
-        rho = _rho_value(cfg, state.sigma, n_blocks_scale=float(n_pods), reg=reg)
+        with span("rho", cat="driver"):
+            rho = _rho_value(
+                cfg, state.sigma, n_blocks_scale=float(n_pods), reg=reg
+            )
         round_fn = make_distributed_round(
             cfg, mesh, axes, m, data.n_max, d, rho,
             structured=isinstance(state.sigma, LowRankDiagSigma),
@@ -536,62 +543,65 @@ def fit_distributed(
         key, outer_key = jax.random.split(key)
         round_keys = jax.random.split(outer_key, cfg.rounds)
         for t in range(cfg.rounds):
-            sub = round_keys[t]
-            alpha, W = round_fn(
-                data.x,
-                data.y,
-                data.mask,
-                data.n,
-                state.alpha,
-                state.W,
-                state.sigma,
-                sub,
-            )
-            state = dataclasses.replace(state, alpha=alpha, W=W)
-            commit = rounds_seen + t + 1
-            for g in range(n_workers):
-                record_receipt(
-                    hist,
-                    CommitReceipt(
-                        worker=g, round=rounds_seen + t, staleness=0, lag=0,
-                        tick=commit, version=commit, tau=0,
-                    ),
+            with span("round", cat="driver", outer=p, round=t):
+                alpha, W = round_fn(
+                    data.x,
+                    data.y,
+                    data.mask,
+                    data.n,
+                    state.alpha,
+                    state.W,
+                    state.sigma,
+                    round_keys[t],
                 )
-            hist["tau_trace"].append(0)
-            hist["gate_refusals"].append(0)
+                state = dataclasses.replace(state, alpha=alpha, W=W)
+                commit = rounds_seen + t + 1
+                for g in range(n_workers):
+                    record_receipt(
+                        hist,
+                        CommitReceipt(
+                            worker=g, round=rounds_seen + t, staleness=0,
+                            lag=0, tick=commit, version=commit, tau=0,
+                        ),
+                    )
+                hist["tau_trace"].append(0)
+                hist["gate_refusals"].append(0)
             if track:
-                dd, pp = objectives(state.alpha, state.sigma)
-                hist["round"].append(commit)
-                hist["tick"].append(commit)
-                hist["dual"].append(float(dd))
-                hist["primal"].append(float(pp))
-                hist["gap"].append(float(pp - dd))
-                hist["min_round"].append(rounds_seen + t + 1)
+                with span("objectives", cat="driver", outer=p, round=t):
+                    dd, pp = objectives(state.alpha, state.sigma)
+                    hist["round"].append(commit)
+                    hist["tick"].append(commit)
+                    hist["dual"].append(float(dd))
+                    hist["primal"].append(float(pp))
+                    hist["gap"].append(float(pp - dd))
+                    hist["min_round"].append(rounds_seen + t + 1)
         rounds_seen += cfg.rounds
         if reg.learns:
-            # Omega-step must see only the REAL tasks: padded (inert) tasks
-            # would otherwise distort the trace-1 normalization.
-            W_true = state.W[: raw.m]
-            sigma_t, omega_t = reg.step(W_true, cfg.omega_jitter)
-            sigma, omega = pad_sigma_any(
-                sigma_t, omega_t, m, raw.m, cfg.omega_jitter
-            )
-            state = dataclasses.replace(
-                state,
-                sigma=device_put_sigma(sigma, mesh, axes),
-                omega=device_put_omega(omega, mesh, axes),
-            )
-            state = dataclasses.replace(
-                state, W=w_from_alpha(state.alpha, state.sigma)
-            )
+            with span("omega_step", cat="driver", outer=p):
+                # Omega-step must see only the REAL tasks: padded (inert)
+                # tasks would otherwise distort the trace-1 normalization.
+                W_true = state.W[: raw.m]
+                sigma_t, omega_t = reg.step(W_true, cfg.omega_jitter)
+                sigma, omega = pad_sigma_any(
+                    sigma_t, omega_t, m, raw.m, cfg.omega_jitter
+                )
+                state = dataclasses.replace(
+                    state,
+                    sigma=device_put_sigma(sigma, mesh, axes),
+                    omega=device_put_omega(omega, mesh, axes),
+                )
+                state = dataclasses.replace(
+                    state, W=w_from_alpha(state.alpha, state.sigma)
+                )
 
-    hist_np = {k: np.asarray(v) for k, v in hist.items()}
-    # un-pad the task axis before returning
-    W = np.asarray(state.W)[: raw.m, : raw.d]
-    if isinstance(state.sigma, SigmaView):
-        from .sigma_view import maybe_dense
+    with span("result", cat="driver"):
+        hist_np = {k: np.asarray(v) for k, v in hist.items()}
+        # un-pad the task axis before returning
+        W = np.asarray(state.W)[: raw.m, : raw.d]
+        if isinstance(state.sigma, SigmaView):
+            from .sigma_view import maybe_dense
 
-        sigma = maybe_dense(state.sigma.unpad(raw.m))
-    else:
-        sigma = np.asarray(state.sigma)[: raw.m, : raw.m]
+            sigma = maybe_dense(state.sigma.unpad(raw.m))
+        else:
+            sigma = np.asarray(state.sigma)[: raw.m, : raw.m]
     return W, sigma, state, hist_np

@@ -205,6 +205,7 @@ def sdca_block_kernel(
         ],
         out_specs=pl.BlockSpec((1, B), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, B), jnp.float32),
+        name="sdca_block",
         scratch_shapes=[
             pltpu.VMEM((1, B), jnp.float32),
             pltpu.VMEM((1, B), jnp.float32),
@@ -337,6 +338,7 @@ def sdca_round_kernel(
             jax.ShapeDtypeStruct((1, n_pad), jnp.float32),
             jax.ShapeDtypeStruct((1, d_pad), jnp.float32),
         ),
+        name="sdca_round",
         scratch_shapes=[
             pltpu.VMEM((block, d_pad), jnp.float32),
             pltpu.VMEM((block, block), jnp.float32),

@@ -48,7 +48,6 @@ from .metrics import (  # noqa: F401
     MetricsRegistry,
     get_registry,
     publish_serving_metrics,
-    publish_staleness,
     publish_wire_stats,
 )
 from .export import (  # noqa: F401
@@ -74,7 +73,6 @@ __all__ = [
     "get_registry",
     "publish_wire_stats",
     "publish_serving_metrics",
-    "publish_staleness",
     "to_prometheus",
     "MetricsHTTPServer",
     "JsonlExporter",

@@ -21,9 +21,6 @@ Bridges absorb the pre-existing ad-hoc telemetry into this schema:
     ``serve.metrics.ServingMetrics`` summary lands as ``repro_serve_*``
     gauges (the machine-readable signals the ROADMAP's autoscaling item
     needs: shed/queue depth/tile fill/latency quantiles).
-  * ``publish_staleness(summary, ...)`` — a
-    ``convergence.staleness_summary`` dict lands as
-    ``repro_transport_staleness_*`` gauges.
 
 Everything here is exportable via ``obs.export`` (Prometheus text
 format, JSONL snapshots).
@@ -42,7 +39,6 @@ __all__ = [
     "get_registry",
     "publish_wire_stats",
     "publish_serving_metrics",
-    "publish_staleness",
 ]
 
 _NAME_RE = re.compile(r"^[a-zA-Z_:][a-zA-Z0-9_:]*$")
@@ -288,26 +284,6 @@ def publish_wire_stats(
             f"transport wire_stats[{key}] (cumulative per run)",
             labels=("transport", "codec", "topology"),
         ).set(float(value), **labels)
-
-
-def publish_staleness(
-    summary: Dict[str, object],
-    *,
-    transport: str,
-    registry: Optional[MetricsRegistry] = None,
-) -> None:
-    """Publish a ``convergence.staleness_summary`` dict as
-    ``repro_transport_staleness_*`` gauges (per-worker/per-edge breakdown
-    dicts are skipped — those stay in the history/trace)."""
-    reg = registry if registry is not None else _REGISTRY
-    for key, value in summary.items():
-        if isinstance(value, dict):
-            continue
-        reg.gauge(
-            f"repro_transport_staleness_{key}",
-            f"staleness_summary[{key}] of the latest run",
-            labels=("transport",),
-        ).set(float(value), transport=transport)
 
 
 # ServingMetrics.summary() scalar keys -> gauge suffixes; latency/ttft

@@ -13,11 +13,21 @@ nesting from time containment of complete ("ph": "X") events on one
 thread track, so a worker thread's ``round`` span visually contains its
 ``gate`` / ``solve`` / ``commit`` children with no explicit parent ids.
 
+While a JAX profiler session records (``jax.profiler.start_trace``), every
+span also enters ``jax.profiler.TraceAnnotation(f"{cat}.{name}", **args)``,
+whether or not ``obs.enable()`` ran: the span then lies on the host plane
+of the profiler's trace, on the same clock as the device's operations
+(``driver.round``, ``transport.commit``, ``serve.run_tile``).  JAX is
+imported lazily, only once something else has imported it: without JAX no
+session can record, and ``obs`` imports without it.
+
 Design constraints (measured by ``benchmarks/bench_obs.py``):
 
-  * **nearly free when disabled** — ``span()`` is one module-global flag
-    check returning a shared no-op context manager; no allocation, no
-    lock, no clock read.  ``obs.disable()`` is the production default.
+  * **nearly free when disabled** — with obs disabled and no profiler
+    session recording, ``span()`` is one module-global flag check and one
+    profiler check returning a shared no-op context manager; no
+    allocation, no lock, no clock read.  ``obs.disable()`` is the
+    production default.
   * **injectable clock** — ``set_clock`` swaps ``time.perf_counter`` for
     a virtual clock so deterministic fleet sims trace in virtual time.
   * **thread-safe** — the only shared mutation is the ring-buffer append
@@ -33,6 +43,7 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -175,17 +186,23 @@ class Tracer:
 
 
 class _Span:
-    """One live span: clock at enter, record at exit."""
+    """One live span: clock at enter, record at exit; ``annotation`` (the
+    profiler's, or None) is entered and left around it."""
 
-    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0", "_annotation")
 
-    def __init__(self, tracer: Tracer, name: str, cat: str, args: dict):
+    def __init__(
+        self, tracer: Tracer, name: str, cat: str, args: dict, annotation=None
+    ):
         self._tracer = tracer
         self._name = name
         self._cat = cat
         self._args = args
+        self._annotation = annotation
 
     def __enter__(self) -> "_Span":
+        if self._annotation is not None:
+            self._annotation.__enter__()
         self._t0 = self._tracer.clock()
         return self
 
@@ -194,6 +211,8 @@ class _Span:
         self._tracer.record(
             self._name, self._cat, self._t0, t1 - self._t0, self._args
         )
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
         return False
 
 
@@ -212,14 +231,39 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 _TRACER = Tracer()
 _ENABLED = False
+_ANNOTATION = None  # jax.profiler.TraceAnnotation, once JAX is imported
+_RECORDING = None  # TraceAnnotation.is_enabled: a profiler session records
+_MODULES = sys.modules
+
+
+def _bind_profiler():
+    """Look up the profiler once JAX is imported: before that, no session
+    can record."""
+    global _ANNOTATION, _RECORDING
+    if _MODULES["jax"] is None:  # imports of JAX are blocked
+        return None
+    from jax.profiler import TraceAnnotation
+
+    _ANNOTATION = TraceAnnotation
+    _RECORDING = TraceAnnotation.is_enabled
+    return _RECORDING
 
 
 def span(name: str, cat: str = "repro", **args):
-    """Context manager timing one phase; a no-op unless ``obs.enable()``
-    ran.  Keyword labels land in the Chrome-trace ``args`` pane."""
+    """Context manager timing one phase.  It records into the ring buffer
+    once ``obs.enable()`` ran, and onto the profiler's trace as
+    ``{cat}.{name}`` while a JAX profiler session records; otherwise it is
+    a shared no-op.  Keyword labels land in the Chrome-trace ``args`` pane
+    and as the profiler event's stats."""
+    recording = _RECORDING
+    if recording is None and "jax" in _MODULES:
+        recording = _bind_profiler()
+    annotation = None
+    if recording is not None and recording():
+        annotation = _ANNOTATION(f"{cat}.{name}", **args)
     if not _ENABLED:
-        return _NULL_SPAN
-    return _Span(_TRACER, name, cat, args)
+        return _NULL_SPAN if annotation is None else annotation
+    return _Span(_TRACER, name, cat, args, annotation)
 
 
 def enable(

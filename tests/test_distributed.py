@@ -29,6 +29,55 @@ def test_one_device_mesh_equals_reference(
     np.testing.assert_allclose(sigma, np.asarray(res.sigma), atol=1e-5)
 
 
+def test_driver_spans_reach_the_profiler(
+    small_problem, small_cfg, one_device_mesh, tmp_path
+):
+    """Under a profiler session, one fit through the estimator writes, on
+    the host plane and inside ``driver.engine_run``: one ``driver.shard``,
+    ``driver.rho`` and ``driver.omega_step`` each outer iteration,
+    ``driver.round`` and ``driver.objectives`` each round, one
+    ``driver.result``."""
+    import collections
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from repro.core import DMTRLEstimator
+
+    est = DMTRLEstimator(
+        engine="distributed", mesh=one_device_mesh, config=small_cfg
+    )
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        est.fit(small_problem.train)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    spans = [
+        (ev.name, ev.start_ns, ev.start_ns + ev.duration_ns, dict(ev.stats))
+        for plane in ProfileData.from_file(path).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for ev in line.events
+        if ev.name.startswith("driver.")
+    ]
+    P, T = small_cfg.outer_iters, small_cfg.rounds
+    count = collections.Counter(n for n, _, _, _ in spans)
+    assert count == {
+        "driver.engine_run": 1, "driver.shard": 1, "driver.rho": P,
+        "driver.round": P * T, "driver.objectives": P * T,
+        "driver.omega_step": P, "driver.result": 1,
+    }
+    rounds = sorted((a["outer"], a["round"]) for n, _, _, a in spans if n == "driver.round")
+    assert rounds == [(p, t) for p in range(P) for t in range(T)]
+    assert sorted(a["outer"] for n, _, _, a in spans if n == "driver.omega_step") == list(range(P))
+    ((_, lo, hi, _),) = [s for s in spans if s[0] == "driver.engine_run"]
+    assert all(lo <= s and e <= hi for _, s, e, _ in spans)
+
+
 @pytest.mark.parametrize("engine", ["distributed", "async"])
 def test_explicit_mesh_is_rejected(small_problem, small_cfg, engine):
     """jax.make_mesh's default Explicit axes break the engines' host-side

@@ -169,6 +169,89 @@ def test_concurrent_spans_stay_well_formed(clean_obs):
             stack.append(t1)
 
 
+def _profiled(tmp_path, body):
+    """Run ``body()`` under a JAX profiler session (no Python tracer) and
+    return the host plane's events as [(name, stats)]."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    return [
+        (ev.name, dict(ev.stats))
+        for plane in ProfileData.from_file(path).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for ev in line.events
+    ]
+
+
+def test_span_reaches_the_profiler_while_a_session_records(tmp_path, clean_obs):
+    """obs disabled: under a profiler session ``span`` is the profiler's
+    annotation, named ``{cat}.{name}`` with its labels as stats; outside
+    the session it is the shared no-op again."""
+    import jax
+
+    def body():
+        assert obs.span("x") is not obs.span("y")
+        with obs.span("commit", cat="transport", worker=3, round=7):
+            with obs.span("solve", cat="transport"):
+                pass
+
+    events = _profiled(tmp_path, body)
+    assert ("transport.commit", {"worker": 3, "round": 7}) in events
+    assert [n for n, _ in events].count("transport.solve") == 1
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    assert obs.span("a") is obs.span("b", cat="x", k=1)
+    assert obs.get_tracer().events() == []
+
+
+def test_enabled_span_records_in_both_sinks(tmp_path, clean_obs):
+    tracer = obs.enable(clear=True)
+
+    def body():
+        with obs.span("omega_step", cat="driver", outer=2):
+            pass
+
+    events = _profiled(tmp_path, body)
+    obs.disable()
+    assert ("driver.omega_step", {"outer": 2}) in events
+    (e,) = tracer.events()
+    assert (e["name"], e["cat"], e["args"]) == ("omega_step", "driver", {"outer": 2})
+
+
+def test_obs_imports_and_spans_without_jax():
+    """JAX is looked up lazily: with it unimportable, obs still imports
+    and a span is the shared no-op (disabled) or a ring-buffer span."""
+    import os
+    import subprocess
+    import sys
+
+    code = (
+        "import sys; sys.modules['jax'] = None\n"
+        "from repro import obs\n"
+        "assert obs.span('a') is obs.span('b')\n"
+        "t = obs.enable(clear=True)\n"
+        "with obs.span('c', cat='driver'): pass\n"
+        "assert [e['name'] for e in t.events()] == ['c']\n"
+        "print('ok')\n"
+    )
+    src = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
+    out = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        env={**os.environ, "PYTHONPATH": src}, timeout=60,
+    )
+    assert out.returncode == 0 and out.stdout.strip() == "ok", out.stderr
+
+
 def test_enable_capacity_change_rebuilds_ring(clean_obs):
     t1 = obs.enable(capacity=8, clear=True)
     t2 = obs.enable(capacity=8)  # same capacity: same tracer
