@@ -82,13 +82,14 @@ def load_file(path: str):
 
 
 def of(tr):
-    """The ProgramTrace of the reduced trace ``tr``, or None.
+    """The ProgramTrace of the reduced trace ``tr``; None where ``tr`` is.
 
     A metric's reader gets the reduced trace alone. Its file is the one
     that ``load_file`` read, or else the profile under the ``trace_dir``
     of the caller that holds ``tr``: the benchmark's ``run_cell``. What is
     read is kept on ``tr``, so that every reader of a run reads the file
-    once.
+    once. A trace whose file neither names raises: a reader that cannot
+    find its profile must not fall silent.
     """
     if tr is None:
         return None
@@ -100,7 +101,12 @@ def of(tr):
                 tr.program_trace = read_file(find_xplane(scope["trace_dir"]))
                 break
             frame = frame.f_back
-    return getattr(tr, "program_trace", None)
+        else:
+            raise LookupError(
+                "no profile for this trace: read it with program_trace.load_file, "
+                "or from a caller that holds it beside a str trace_dir"
+            )
+    return tr.program_trace
 
 
 def intersect(a, b):
@@ -135,7 +141,7 @@ def subtract(a, b):
     return out
 
 
-def omega_intervals(prog):
+def omega_spans(prog):
     """Each Omega-step, from the start of its ``driver.omega_step`` span to
     the end of the first ``driver.rho`` or ``driver.result`` span after it:
     the readback that waits for the step's device work."""
@@ -146,6 +152,13 @@ def omega_intervals(prog):
         if after:
             out.append((s, min(after)))
     return out
+
+
+def omega_intervals(prog):
+    """``omega_spans`` less those that hold a JAX compile event (a retrace
+    of ``w_from_alpha``, or of the Omega-step's programs): their time is
+    compile_share's, as for ``round_trip_intervals``."""
+    return [(s, e) for s, e in omega_spans(prog) if not prog.holds_compile(s, e)]
 
 
 def omega_share(tr, prog):
@@ -169,12 +182,12 @@ def round_trip_intervals(prog):
 
 
 def compile_intervals(prog):
-    """JAX's compile events, and the ``driver.round`` and
-    ``driver.objectives`` spans that hold one: these also fetch the
-    program from the persistent cache and load it, which JAX does not
-    annotate."""
+    """JAX's compile events, and the round trips and Omega-steps that hold
+    one: these also fetch the program from the persistent cache and load
+    it, which JAX does not annotate."""
+    held = [(s, e) for _, s, e in prog.named(*ROUND_TRIP)] + omega_spans(prog)
     return [(s, e) for _, s, e in prog.compile_spans] + [
-        (s, e) for _, s, e in prog.named(*ROUND_TRIP) if prog.holds_compile(s, e)
+        (s, e) for s, e in held if prog.holds_compile(s, e)
     ]
 
 

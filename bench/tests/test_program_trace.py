@@ -32,7 +32,8 @@ def _hand():
 
     Rounds 0..200 (holds a compile event at 10..90), 250..330 and
     400..450; objectives 330..360; an Omega-step 450..550 read back by
-    rho 560..720; shard 900..950.
+    rho 560..720, another 750..800 (holds a compile event at 760..790)
+    read back by result 800..850; shard 900..950.
     """
     dev = trace.Device("/device:TPU:0", [("op", 100, 150), ("op", 300, 320), ("op", 600, 700)], [])
     tr = trace.Trace([("window", 0, 1000), ("fit", 0, 1000)], [dev], (0, 1000))
@@ -41,9 +42,10 @@ def _hand():
             ("driver.round", 0, 200), ("driver.round", 250, 330),
             ("driver.objectives", 330, 360), ("driver.round", 400, 450),
             ("driver.omega_step", 450, 550), ("driver.rho", 560, 720),
+            ("driver.omega_step", 750, 800), ("driver.result", 800, 850),
             ("driver.shard", 900, 950),
         ],
-        [("backend_compile_and_load", 10, 90)],
+        [("backend_compile_and_load", 10, 90), ("trace_to_jaxpr_dynamic", 760, 790)],
     )
     return tr, prog
 
@@ -58,23 +60,26 @@ def test_interval_intersect_and_subtract():
 
 def test_hand_counts():
     tr, prog = _hand()
-    # the round holding a compile event is left out, and counts as compile
+    # the round and the Omega-step holding a compile event are left out,
+    # and count as compile
     assert pt.round_trip_intervals(prog) == [(250, 330), (330, 360), (400, 450)]
-    assert pt.compile_intervals(prog) == [(10, 90), (0, 200)]
+    assert pt.compile_intervals(prog) == [(10, 90), (760, 790), (0, 200), (750, 850)]
     # 250..360 holds 20 ns busy, 400..450 none: 90 + 50 idle of 1000
     assert pt.round_idle_share(tr, prog) == pytest.approx(14.0)
-    # Omega-step 450 to the end of the rho after it, 720
+    # Omega-step 450 to the end of the rho after it, 720; the one at 750
+    # to the end of the result, 850, holds a compile event
+    assert pt.omega_spans(prog) == [(450, 720), (750, 850)]
     assert pt.omega_intervals(prog) == [(450, 720)]
     assert pt.omega_share(tr, prog) == pytest.approx(27.0)
     split = pt.idle_split(tr, prog)
     assert split == pytest.approx(
-        {"compile": 150e-9, "round trip": 140e-9, "Omega-step": 170e-9,
-         "shard": 50e-9, "other": 320e-9}
+        {"compile": 250e-9, "round trip": 140e-9, "Omega-step": 170e-9,
+         "shard": 50e-9, "other": 220e-9}
     )
     assert sum(split.values()) == pytest.approx(tr.window_s - tr.busy_s())
     # gaps 700..1000, 320..600, 150..300, 0..100, named at their midpoints
     assert pt.idle_gaps(tr, prog) == [
-        ("fit", pytest.approx(300e-9)), ("driver.omega_step", pytest.approx(280e-9)),
+        ("driver.result", pytest.approx(300e-9)), ("driver.omega_step", pytest.approx(280e-9)),
         ("fit", pytest.approx(150e-9)), ("backend_compile_and_load", pytest.approx(100e-9)),
     ]
     assert [n for n, _ in tr.idle_gaps()] == ["fit"] * 4
@@ -86,28 +91,71 @@ def test_readers_without_program_spans_return_none():
     assert pt.omega_share(tr, empty) is None
     assert pt.round_idle_share(tr, empty) is None
     assert pt.omega_share(None, None) is None
+    # a trace read from a program without the driver's spans
+    tr.program_trace = empty
     run = _run(tr)
-    # a trace no file or caller holds: nothing to read
     assert reader("omega_share.train")(run) is None
     assert reader("round_idle_share.train")(run) is None
+    # an untraced run
+    untraced = Run(1, peak("TPU v5 lite"), 1.0, {}, None, None)
+    assert reader("omega_share.train")(untraced) is None
+    assert reader("round_idle_share.train")(untraced) is None
+
+
+def test_reader_without_its_profile_raises():
+    """A traced run whose profile neither load_file nor a caller's
+    trace_dir names: the reader raises rather than fall silent."""
+    tr, _ = _hand()
+    with pytest.raises(LookupError):
+        reader("omega_share.train")(_run(tr))
+    with pytest.raises(LookupError):
+        reader("round_idle_share.train")(_run(tr))
+
+
+# What the benchmark's own reduction and readers gave on the traces
+# recorded before the program had driver spans, before program_trace.py
+# existed: window_s, busy_s, units, the longest idle gap, and the readers.
+BEFORE = {
+    "small_train.xplane.pb": {
+        "window_s": 2.19163305, "busy_s": 0.075247745, "units": 1,
+        "gap": ("fit", 0.3813474),
+        "readers": {
+            "idle_share.train": 96.56659015066413,
+            "idle_share.score": 96.56659015066413,
+            "collective_share.mesh4": None,
+            "round_roofline": 0.05480936059154186,
+        },
+    },
+    "small_score.xplane.pb": {
+        "window_s": 0.303869804, "busy_s": 0.000223991, "units": 128,
+        "gap": ("window", 0.003410658),
+        "readers": {
+            "idle_share.train": 99.92628718054526,
+            "idle_share.score": 99.92628718054526,
+            "collective_share.mesh4": None,
+            "round_roofline": None,
+        },
+    },
+}
 
 
 @pytest.mark.parametrize("name", RECORDED)
 def test_old_traces_read_as_before(name):
     """Traces recorded before the program had driver spans: the harness's
-    reduction reads them as it always did, every existing reader gives
-    the same value, and the new readers find no span to read."""
-    path = str(DATA / name)
-    tr, prog = pt.load_file(path)
-    old = trace.load_file(path)
-    assert tr.spans == old.spans and tr.window == old.window
-    assert tr.busy_s() == old.busy_s() and tr.idle_gaps() == old.idle_gaps()
-    assert tr.top_ops() == old.top_ops() and tr.units() == old.units()
+    reduction and every existing reader give the values they gave before
+    (``BEFORE``), and the new readers find no span to read."""
+    before = BEFORE[name]
+    tr, prog = pt.load_file(str(DATA / name))
+    assert tr.window_s == pytest.approx(before["window_s"], rel=1e-12)
+    assert tr.busy_s() == pytest.approx(before["busy_s"], rel=1e-12)
+    assert tr.units() == before["units"]
+    gap_name, gap_s = tr.idle_gaps()[0]
+    assert (gap_name, gap_s) == (before["gap"][0], pytest.approx(before["gap"][1], rel=1e-12))
     assert not [n for n, _, _ in prog.program_spans if n.startswith("driver.")]
-    counters = {"d": 28, "H": 128, "tasks": 139, "samples": 11592}
-    run, run_old = _run(tr, counters), _run(old, counters)
-    for metric in ("idle_share.train", "idle_share.score", "collective_share.mesh4", "round_roofline"):
-        assert reader(metric)(run) == reader(metric)(run_old)
+    run = _run(tr, {"d": 28, "H": 128, "tasks": 139, "samples": 11592})
+    for metric, value in before["readers"].items():
+        got = reader(metric)(run)
+        assert got == (None if value is None else pytest.approx(value, rel=1e-12)), metric
     assert reader("omega_share.train")(run) is None
     assert reader("round_idle_share.train")(run) is None
 
