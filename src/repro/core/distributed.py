@@ -18,7 +18,7 @@ which is the paper's m*d-floats-per-round communication pattern.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -29,7 +29,13 @@ from ..launch.mesh import require_auto_axes
 from ..obs.trace import span
 from . import omega as omega_mod
 from . import omega_regularizers as omega_reg
-from .dmtrl import DMTRLConfig, WarmStart, _rho_value, make_data_fns
+from .dmtrl import (
+    DMTRLConfig,
+    WarmStart,
+    _rho_value,
+    driver_program,
+    make_data_fns,
+)
 from .losses import get_loss
 from .mtl_data import MTLData
 from .sigma_view import LowRankDiagSigma, SigmaView
@@ -403,6 +409,29 @@ def round_shard_map(cfg: DMTRLConfig, axes: MeshAxes, body, mesh, in_specs, out_
     )
 
 
+# the DMTRLConfig fields a round program's trace reads: with the mesh, the
+# axes, the shapes and the builders below, its static key
+_ROUND_FIELDS = (
+    "loss", "lam", "eta", "solver", "block_size", "local_iters",
+    "dist_block_hoisted", "gram_bf16",
+)
+
+
+@dataclasses.dataclass(frozen=True)
+class RhoRound:
+    """A cached round program with one outer iteration's ``rho`` bound:
+    ``round(x, y, mask, n, alpha, W, sigma, key) -> (alpha, W)``."""
+
+    program: Callable
+    rho: float
+
+    def __call__(self, *args):
+        return self.program(*args, self.rho)
+
+    def lower(self, *args):
+        return self.program.lower(*args, self.rho)
+
+
 def make_distributed_round(
     cfg: DMTRLConfig,
     mesh: Mesh,
@@ -413,9 +442,14 @@ def make_distributed_round(
     rho: float,
     structured: bool = False,
 ):
-    """Build the jitted one-round function over sharded global arrays.
+    """The jitted one-round function over sharded global arrays, at ``rho``.
 
     round(x, y, mask, n, alpha, W, sigma, key) -> (alpha, W)
+
+    ``rho`` is a runtime operand of the program, which is built once per
+    static key (the config fields in ``_ROUND_FIELDS``, mesh, axes, shapes,
+    ``structured`` and the ``make_local_solve`` / ``server_reduce`` it is
+    built from) and reused for every ``rho`` and every fit.
 
     With ``structured=True`` the sigma argument is a LowRankDiagSigma pytree
     (U/d row-sharded, core replicated) and the server reduce is factored:
@@ -425,32 +459,46 @@ def make_distributed_round(
     dW_rows = U_rows (C psum) + d_rows * db locally. The dense and factored
     reduces agree to float tolerance (parity-tested).
     """
+    round_cfg = DMTRLConfig(**{f: getattr(cfg, f) for f in _ROUND_FIELDS})
+    program = _round_program(
+        round_cfg, mesh, axes, m, n_max, d, structured,
+        make_local_solve, server_reduce,
+    )
+    return RhoRound(program, float(rho))
+
+
+@driver_program("round")
+def _round_program(
+    cfg, mesh, axes, m, n_max, d, structured, local_solve_fn, reduce_fn
+):
     structured_specs = LowRankDiagSigma(
         U=P(axes.data, None), core=P(), d=P(axes.data)
-    )
-    local_solve = make_local_solve(
-        cfg, mesh, axes, m, n_max, d, rho,
-        sigma_input="diag" if structured else "rows",
     )
     base_specs = round_in_specs(axes)
     if structured:
         base_specs = base_specs[:-1] + (structured_specs,)
-    in_specs = base_specs + (P(),)  # + key (replicated)
+    in_specs = base_specs + (P(), P())  # + key, rho (replicated)
     out_specs = round_out_specs(axes)
+
+    def local_solve(rho, *args):
+        return local_solve_fn(
+            cfg, mesh, axes, m, n_max, d, rho,
+            sigma_input="diag" if structured else "rows",
+        )(*args)
 
     if structured:
 
-        def round_body(x, y, mask, n, alpha, W, sv, key):
-            dalpha, db = local_solve(x, y, n, alpha, W, sv.diag(), key)
+        def round_body(x, y, mask, n, alpha, W, sv, key, rho):
+            dalpha, db = local_solve(rho, x, y, n, alpha, W, sv.diag(), key)
             proj = jax.lax.psum(sv.U.T @ db, axes.data)  # (r, d_loc)
             dW = (sv.U @ (sv.core @ proj) + sv.d[:, None] * db) / cfg.lam
             return alpha + cfg.eta * dalpha, W + dW
 
     else:
 
-        def round_body(x, y, mask, n, alpha, W, sigma_rows, key):
-            dalpha, db = local_solve(x, y, n, alpha, W, sigma_rows, key)
-            dW = server_reduce(cfg, axes, sigma_rows, db)
+        def round_body(x, y, mask, n, alpha, W, sigma_rows, key, rho):
+            dalpha, db = local_solve(rho, x, y, n, alpha, W, sigma_rows, key)
+            dW = reduce_fn(cfg, axes, sigma_rows, db)
             return alpha + cfg.eta * dalpha, W + dW
 
     shmapped = round_shard_map(cfg, axes, round_body, mesh, in_specs, out_specs)

@@ -18,6 +18,7 @@ per-round math; this module is the semantic oracle it is tested against.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import numbers
 from typing import Dict, List, Optional, Union
 
@@ -28,6 +29,7 @@ import numpy as np
 from . import dual as dual_mod
 from . import omega_regularizers as omega_reg
 from . import sigma_view as sigma_view_mod
+from ..obs.metrics import get_registry
 from .losses import get_loss
 from .mtl_data import MTLData
 from .sigma_view import SigmaView
@@ -302,26 +304,72 @@ def make_w_step_round(cfg: DMTRLConfig, n_max: int, rho: float):
     return round_fn
 
 
+def driver_program(name: str):
+    """Memoise a builder of jitted programs on its static key.
+
+    A fresh ``jax.jit`` starts with an empty cache, so a program rebuilt per
+    fit (or per outer iteration) is traced and lowered again each time.
+    The builder's arguments are the key: everything the program's trace
+    reads, and no data, seed or ``rho`` value. Each lookup counts in
+    ``repro_engine_driver_programs_total{program, outcome}``, ``outcome``
+    ``built`` or ``reused``.
+    """
+
+    def wrap(build):
+        cached = functools.lru_cache(maxsize=16)(build)
+
+        @functools.wraps(build)
+        def lookup(*key):
+            misses = cached.cache_info().misses
+            program = cached(*key)
+            built = cached.cache_info().misses > misses
+            get_registry().counter(
+                "repro_engine_driver_programs_total",
+                "jitted driver programs looked up, by program and outcome",
+                labels=("program", "outcome"),
+            ).inc(program=name, outcome="built" if built else "reused")
+            return program
+
+        lookup.cache_clear = cached.cache_clear
+        return lookup
+
+    return wrap
+
+
+@driver_program("objectives")
+def _objectives_program(lam: float, loss_name: str):
+    loss = get_loss(loss_name)
+
+    @jax.jit
+    def objectives(data, alpha, sigma):
+        dd = dual_mod.dual_objective(data, alpha, sigma, lam, loss)
+        pp = dual_mod.primal_objective_from_alpha(data, alpha, sigma, lam, loss)
+        return dd, pp
+
+    return objectives
+
+
+@driver_program("w_from_alpha")
+def _w_from_alpha_program(lam: float):
+    @jax.jit
+    def w_from_alpha(data, alpha, sigma):
+        return dual_mod.weights_from_alpha(data, alpha, sigma, lam)
+
+    return w_from_alpha
+
+
 def make_data_fns(cfg: DMTRLConfig, data: MTLData):
     """Jitted ``objectives(alpha, sigma) -> (dual, primal)`` and
     ``w_from_alpha(alpha, sigma) -> W`` over ``data``.
 
     The data enters the compiled programs as an argument: an array closed
     over by a jitted function is baked into the program as a constant, and
-    at MNIST width ``x`` alone is 376 MB.
+    at MNIST width ``x`` alone is 376 MB. The programs are shared by every
+    fit with the same ``(lam, loss)``, so a refit of same-shaped data
+    traces nothing.
     """
-    loss = get_loss(cfg.loss)
-
-    @jax.jit
-    def objectives(data, alpha, sigma):
-        dd = dual_mod.dual_objective(data, alpha, sigma, cfg.lam, loss)
-        pp = dual_mod.primal_objective_from_alpha(data, alpha, sigma, cfg.lam, loss)
-        return dd, pp
-
-    @jax.jit
-    def w_from_alpha(data, alpha, sigma):
-        return dual_mod.weights_from_alpha(data, alpha, sigma, cfg.lam)
-
+    objectives = _objectives_program(cfg.lam, cfg.loss)
+    w_from_alpha = _w_from_alpha_program(cfg.lam)
     return (
         lambda alpha, sigma: objectives(data, alpha, sigma),
         lambda alpha, sigma: w_from_alpha(data, alpha, sigma),

@@ -4,6 +4,7 @@ The 1-device mesh case runs in-process; the real multi-device cases run in a
 subprocess with XLA_FLAGS=--xla_force_host_platform_device_count=8 (device
 count must be set before jax initializes).
 """
+import dataclasses
 import json
 import os
 import subprocess
@@ -76,6 +77,123 @@ def test_driver_spans_reach_the_profiler(
     assert sorted(a["outer"] for n, _, _, a in spans if n == "driver.omega_step") == list(range(P))
     ((_, lo, hi, _),) = [s for s in spans if s[0] == "driver.engine_run"]
     assert all(lo <= s and e <= hi for _, s, e, _ in spans)
+
+
+def _program_counts():
+    from repro.obs.metrics import get_registry
+
+    counter = get_registry().counter(
+        "repro_engine_driver_programs_total", labels=("program", "outcome")
+    )
+    return {
+        (p, o): counter.value(program=p, outcome=o)
+        for p in ("round", "objectives", "w_from_alpha")
+        for o in ("built", "reused")
+    }
+
+
+def _programs_since(before):
+    return {k: v - before[k] for k, v in _program_counts().items() if v > before[k]}
+
+
+@pytest.fixture
+def fresh_programs():
+    """Empty the driver's program memos, so a test sees its own builds."""
+    from repro.core import distributed, dmtrl
+
+    for memo in (
+        distributed._round_program,
+        dmtrl._objectives_program,
+        dmtrl._w_from_alpha_program,
+    ):
+        memo.cache_clear()
+
+
+@pytest.mark.parametrize("solver", ["block_gram", "pallas_round"])
+def test_refit_lowers_nothing(small_problem, small_cfg, one_device_mesh, solver):
+    """A second fit of same-shaped data through the estimator reuses every
+    program of the first: JAX reports no lowering to MLIR while it runs."""
+    import jax
+
+    from repro.core import DMTRLEstimator
+
+    cfg = dataclasses.replace(small_cfg, solver=solver)
+    est = DMTRLEstimator(engine="distributed", mesh=one_device_mesh, config=cfg)
+    est.fit(small_problem.train)
+    lowered = []
+
+    def listen(event, duration, **_):
+        if event == "/jax/core/compile/jaxpr_to_mlir_module_duration":
+            lowered.append(duration)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        est.fit(small_problem.train)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert lowered == []
+
+
+def test_round_program_is_reused_across_rho(
+    small_problem, small_cfg, one_device_mesh, fresh_programs
+):
+    """Two datasets of one shape give different rho in every outer
+    iteration and fit; one round program serves them all, and each fit
+    still equals the single-process reference."""
+    import jax
+
+    from repro.core.mtl_data import MTLData
+
+    a = small_problem.train
+    noise = jax.random.uniform(jax.random.PRNGKey(7), a.x.shape, a.x.dtype)
+    b = MTLData(a.x * (0.5 + noise), a.y, a.mask, a.n)
+    cfg = dataclasses.replace(small_cfg, outer_iters=3)
+    before = _program_counts()
+    rhos = []
+    for data in (a, b):
+        res = fit(cfg, data)
+        rhos.append(res.rho_per_outer)
+        W, sigma, _, _ = fit_distributed(
+            cfg, data, one_device_mesh, MeshAxes(data="data")
+        )
+        np.testing.assert_allclose(W, np.asarray(res.W), atol=2e-4)
+        np.testing.assert_allclose(sigma, np.asarray(res.sigma), atol=1e-5)
+    # every rho differs but the first, which both fits take from Sigma = I/m
+    assert len(set(rhos[0] + rhos[1])) == 2 * cfg.outer_iters - 1
+    counts = _programs_since(before)
+    assert counts[("round", "built")] == 1
+    assert counts[("round", "reused")] == 2 * cfg.outer_iters - 1
+
+
+def test_program_counter_reads_builds_and_reuses(
+    small_problem, small_cfg, one_device_mesh, fresh_programs, monkeypatch
+):
+    """Two fits of 5 outer iterations build each program once; a fault
+    planted in the round's local solve after them builds a new round
+    program, so a sound program built earlier cannot hide it."""
+    from repro.core import DMTRLEstimator
+
+    monkeypatch.syspath_prepend(REPO)
+    from bench.lib import faults
+
+    cfg = dataclasses.replace(small_cfg, outer_iters=5, rounds=2)
+    est = DMTRLEstimator(engine="distributed", mesh=one_device_mesh, config=cfg)
+    before = _program_counts()
+    sound = [est.fit(small_problem.train).W_ for _ in range(2)]
+    assert _programs_since(before) == {
+        ("round", "built"): 1, ("round", "reused"): 9,
+        ("objectives", "built"): 1, ("objectives", "reused"): 1,
+        ("w_from_alpha", "built"): 1, ("w_from_alpha", "reused"): 1,
+    }
+    np.testing.assert_array_equal(sound[0], sound[1])
+
+    faults.plant("half", monkeypatch.setattr)
+    before = _program_counts()
+    broken = est.fit(small_problem.train).W_
+    counts = _programs_since(before)
+    assert counts[("round", "built")] == 1
+    assert counts[("round", "reused")] == 4
+    assert np.max(np.abs(broken - sound[0])) > 1e-3
 
 
 @pytest.mark.parametrize("engine", ["distributed", "async"])
