@@ -62,7 +62,13 @@ from .engines import (
 )
 from .estimator import DMTRLEstimator, NotFittedError
 from .losses import Loss, get_loss, registered_losses
-from .mtl_data import MTLData, from_task_list, normalize_rows
+from .mtl_data import (
+    MTLData,
+    PackedMTLData,
+    from_task_list,
+    normalize_rows,
+    pack_tasks,
+)
 from .omega import (
     correlation_from_sigma,
     init_sigma,
@@ -182,6 +188,8 @@ __all__ = [
     "get_loss",
     "registered_losses",
     "MTLData",
+    "PackedMTLData",
+    "pack_tasks",
     "from_task_list",
     "normalize_rows",
     "correlation_from_sigma",

@@ -71,7 +71,7 @@ from jax.sharding import Mesh
 from . import omega_regularizers as omega_reg
 from .distributed import MeshAxes
 from .dmtrl import DMTRLConfig, WarmStart, _rho_value, validate_async_fields
-from .mtl_data import MTLData
+from .mtl_data import MTLData, refuse_packed
 from .transport import (  # re-exported for backward compatibility
     _adapt_tau,
     _worker_delays,
@@ -174,6 +174,7 @@ def fit_async(
     by the ``simulated`` transport and optional for the host transports
     (they only read its data-axis size when ``n_workers`` is unset).
     """
+    refuse_packed(raw, "the async engine")
     if axes is None:
         axes = MeshAxes()
     if options is not None:

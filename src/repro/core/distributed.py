@@ -14,6 +14,11 @@ One communication round lowers to exactly:
     local  dW = Sigma_rows @ dB / lambda   -- the server reduce, sharded
   (+ psum over 'pod' when present, + the block-Gram psums over 'model')
 which is the paper's m*d-floats-per-round communication pattern.
+
+Packed task storage (core/mtl_data.py:PackedMTLData) shards over the
+``data`` axis alone: each worker holds its tasks' rows back to back,
+padded only to the largest worker's row total, and its local round reads
+task i's sample j at row offset_i + j (``make_local_solve``).
 """
 from __future__ import annotations
 
@@ -26,6 +31,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..launch.mesh import require_auto_axes
+from ..obs.metrics import get_registry
 from ..obs.trace import span
 from . import omega as omega_mod
 from . import omega_regularizers as omega_reg
@@ -37,7 +43,7 @@ from .dmtrl import (
     make_data_fns,
 )
 from .losses import get_loss
-from .mtl_data import MTLData
+from .mtl_data import MTLData, PackedMTLData, row_tasks, worker_layout
 from .sigma_view import LowRankDiagSigma, SigmaView
 from .solver_backends import get_backend
 
@@ -85,9 +91,12 @@ def shard_mtl_data(
 ) -> Tuple[MTLData, int, int]:
     """Pad task count / feature dim / sample dim and device_put with shardings.
 
-    Returns (sharded data, m_padded, d_padded).
+    Returns (sharded data, m_padded, d_padded). Packed data goes through
+    ``_shard_packed_data``.
     """
     require_auto_axes(mesh)
+    if data.layout == "packed":
+        return _shard_packed_data(data, mesh, axes)
     dsz = _axis_size(mesh, axes.data)
     msz = _axis_size(mesh, axes.model)
     psz = _axis_size(mesh, axes.pod)
@@ -112,6 +121,40 @@ def shard_mtl_data(
         jax.device_put(d.n, sn),
     )
     return out, m_pad, d_pad
+
+
+def _shard_packed_data(
+    data: PackedMTLData, mesh: Mesh, axes: MeshAxes
+) -> Tuple[PackedMTLData, int, int]:
+    """Place packed rows on the mesh, tasks dealt to the ``data`` workers in
+    contiguous ranges: each worker's tasks' rows back to back, padded only
+    to the largest worker's row total (``mtl_data.worker_layout``). On one
+    worker the rows are already in place, and nothing is copied.
+
+    Returns (sharded data, m_padded, d).
+    """
+    if axes.model is not None or axes.pod is not None:
+        raise ValueError(
+            "packed task storage shards tasks over the data axis only; "
+            f"got model={axes.model!r}, pod={axes.pod!r}"
+        )
+    workers = _axis_size(mesh, axes.data)
+    x, y, mask, n = data.x, data.y, data.mask, data.n
+    if workers > 1:
+        dst, rows, n = worker_layout(np.asarray(data.n), workers)
+
+        def place(a):
+            out = jnp.zeros((workers * rows,) + a.shape[1:], a.dtype)
+            return out.at[dst].set(a[: dst.shape[0]])
+
+        x, y, mask = place(x), place(y), place(mask)
+    sr = NamedSharding(mesh, P(axes.data, None))
+    sv = NamedSharding(mesh, P(axes.data))
+    out = PackedMTLData(
+        jax.device_put(x, sr), jax.device_put(y, sv), jax.device_put(mask, sv),
+        jax.device_put(n, sv), data.n_max, workers,
+    )
+    return out, out.m, data.d
 
 
 def round_in_specs(axes: MeshAxes):
@@ -141,6 +184,7 @@ def make_local_solve(
     d: int,
     rho: float,
     sigma_input: str = "rows",
+    packed: bool = False,
 ):
     """The worker half of one communication round, as a shard_map body.
 
@@ -155,6 +199,13 @@ def make_local_solve(
     dense (m_loc, m) owned Sigma rows (the historical layout — sigma_ii is
     extracted by global task id), ``"diag"`` just the local (m_loc,)
     diagonal (the structured-Sigma layout: workers never see full rows).
+
+    With ``packed=True`` x is this worker's packed rows (R_loc, d), and y,
+    alpha and dalpha are (R_loc,) in the rows' order (``_shard_packed_data``):
+    task i's sample j is row offset_i + j, offsets the exclusive cumulative
+    sum of n. The coordinates and keys are the padded layout's, so the
+    iterates are too, one for one. Only backends with ``packed`` run there:
+    the Pallas ones run over a task's padded rows.
     """
     if sigma_input not in ("rows", "diag"):
         raise ValueError(f"sigma_input must be 'rows' or 'diag', got {sigma_input!r}")
@@ -164,6 +215,12 @@ def make_local_solve(
     m_loc = m // dsz
     n_loc = n_max // psz
     backend = get_backend(cfg.solver)
+    if packed and not backend.packed:
+        raise ValueError(
+            f"the {cfg.solver} backend runs its Pallas kernel over a task's "
+            "padded rows and refuses packed task storage; use "
+            'solver="block_gram"'
+        )
     H = backend.round_local_iters(cfg.local_iters or n_loc, cfg.block_size)
     # with a sharded feature dim the full-Gram form is used regardless of the
     # configured backend: ONE batched (q, G) build + psum over 'model' for
@@ -172,8 +229,9 @@ def make_local_solve(
     # naive/block (tested). Per-task backends can't psum their own
     # d-contractions from inside a Pallas kernel (docs/DESIGN.md §5).
     use_gram = axes.model is not None
+    packed_kw = {"n_cap": n_max} if packed else {}
     solver = None if use_gram else backend.make(
-        loss, rho, cfg.lam, H, block=cfg.block_size, axis_name=None
+        loss, rho, cfg.lam, H, block=cfg.block_size, axis_name=None, **packed_kw
     )
 
     def local_solve(x, y, n, alpha, W_read, sigma_rows, key):
@@ -271,6 +329,14 @@ def make_local_solve(
                     )
                 )(G, q, alpha, y, coords, n_local, sigma_ii)
                 r = jnp.einsum("mhd,mh->md", Xs, deltas)
+        elif packed:  # x, y and alpha are shared by the tasks' solves
+            offsets = jnp.cumsum(n) - n
+            dalpha, r = jax.vmap(solver, in_axes=(None, None, None, 0, 0, 0, 0, 0))(
+                x, y, alpha, W_read, n, sigma_ii, keys, offsets
+            )  # dalpha (m_loc, n_max), by sample: back to the rows' order
+            t = row_tasks(n, x.shape[0])
+            j = jnp.arange(x.shape[0], dtype=jnp.int32) - offsets[t]
+            dalpha = jnp.where(j < n[t], dalpha[t, jnp.minimum(j, n_max - 1)], 0.0)
         else:
             dalpha, r = jax.vmap(solver)(
                 x, y, alpha, W_read, n_local, sigma_ii, keys
@@ -376,12 +442,17 @@ def install_initial_state(
         omega=device_put_omega(om, mesh, axes),
     )
     if init is not None:
-        alpha0 = jnp.zeros((m, data.n_max), data.x.dtype)
-        alpha0 = alpha0.at[: raw.m, : raw.n_max].set(
-            jnp.asarray(init.alpha, data.x.dtype)
+        alpha_t = jnp.asarray(init.alpha, data.x.dtype)
+        alpha0 = jnp.zeros(data.mask.shape, data.x.dtype)  # alpha follows the mask
+        if data.layout == "padded":
+            alpha0 = alpha0.at[: raw.m, : raw.n_max].set(alpha_t)
+        elif state.rows is not None:
+            alpha0 = alpha0.at[state.rows].set(alpha_t[: state.rows.shape[0]])
+        else:
+            alpha0 = alpha_t
+        state = dataclasses.replace(
+            state, alpha=jax.device_put(alpha0, alpha_sharding(data, mesh, axes))
         )
-        sv = NamedSharding(mesh, P(axes.data, axes.pod))
-        state = dataclasses.replace(state, alpha=jax.device_put(alpha0, sv))
         state = dataclasses.replace(
             state, W=w_from_alpha(state.alpha, state.sigma)
         )
@@ -441,6 +512,7 @@ def make_distributed_round(
     d: int,
     rho: float,
     structured: bool = False,
+    packed: bool = False,
 ):
     """The jitted one-round function over sharded global arrays, at ``rho``.
 
@@ -458,10 +530,14 @@ def make_distributed_round(
     instead of O(m d), the communication win at large m — then applies
     dW_rows = U_rows (C psum) + d_rows * db locally. The dense and factored
     reduces agree to float tolerance (parity-tested).
+
+    With ``packed=True`` the data, alpha and the returned alpha are packed
+    rows (``_shard_packed_data``), ``n_max`` is the largest n_i, and the
+    worker half is ``make_local_solve(..., packed=True)``.
     """
     round_cfg = DMTRLConfig(**{f: getattr(cfg, f) for f in _ROUND_FIELDS})
     program = _round_program(
-        round_cfg, mesh, axes, m, n_max, d, structured,
+        round_cfg, mesh, axes, m, n_max, d, structured, packed,
         make_local_solve, server_reduce,
     )
     return RhoRound(program, float(rho))
@@ -469,21 +545,27 @@ def make_distributed_round(
 
 @driver_program("round")
 def _round_program(
-    cfg, mesh, axes, m, n_max, d, structured, local_solve_fn, reduce_fn
+    cfg, mesh, axes, m, n_max, d, structured, packed, local_solve_fn, reduce_fn
 ):
     structured_specs = LowRankDiagSigma(
         U=P(axes.data, None), core=P(), d=P(axes.data)
     )
-    base_specs = round_in_specs(axes)
+    if packed:  # rows (x, y, mask, alpha) and tasks (n, W, sigma) over data
+        vec, mat = P(axes.data), P(axes.data, None)
+        base_specs = (mat, vec, vec, vec, vec, mat, mat)
+        out_specs = (vec, mat)
+    else:
+        base_specs = round_in_specs(axes)
+        out_specs = round_out_specs(axes)
     if structured:
         base_specs = base_specs[:-1] + (structured_specs,)
     in_specs = base_specs + (P(), P())  # + key, rho (replicated)
-    out_specs = round_out_specs(axes)
 
     def local_solve(rho, *args):
         return local_solve_fn(
             cfg, mesh, axes, m, n_max, d, rho,
             sigma_input="diag" if structured else "rows",
+            **({"packed": True} if packed else {}),
         )(*args)
 
     if structured:
@@ -505,6 +587,21 @@ def _round_program(
     return jax.jit(shmapped)
 
 
+def _count_rows(raw, data, layout: str) -> None:
+    """Gauge ``repro_engine_data_rows{layout, kind}``: the rows of task
+    storage on the mesh (``stored``, padding included) and the tasks' real
+    samples (``real``, the sum of n_i)."""
+    gauge = get_registry().gauge(
+        "repro_engine_data_rows",
+        "rows of task storage on the mesh, padding included (stored) and "
+        "the tasks' samples (real)",
+        labels=("layout", "kind"),
+    )
+    stored = data.x.shape[0] * (1 if layout == "packed" else data.x.shape[1])
+    gauge.set(stored, layout=layout, kind="stored")
+    gauge.set(int(np.asarray(raw.n).sum()), layout=layout, kind="real")
+
+
 @dataclasses.dataclass
 class DistributedState:
     alpha: Array
@@ -513,15 +610,27 @@ class DistributedState:
     sigma: Array
     # precision; None for structured members without a cheap inverse
     omega: Optional[Array]
+    # packed rows on more than one worker: where each of the raw
+    # container's rows lies in ``alpha`` (mtl_data.worker_layout)
+    rows: Optional[np.ndarray] = None
+
+
+def alpha_sharding(data, mesh: Mesh, axes: MeshAxes) -> NamedSharding:
+    """alpha has the mask's shape: (m, n_max) over (data, pod), or the
+    packed rows over data."""
+    if data.layout == "packed":
+        return NamedSharding(mesh, P(axes.data))
+    return NamedSharding(mesh, P(axes.data, axes.pod))
 
 
 def init_state(
     data: MTLData, mesh: Mesh, axes: MeshAxes, m: int, d: int
 ) -> DistributedState:
-    sv = NamedSharding(mesh, P(axes.data, axes.pod))
     sw = NamedSharding(mesh, P(axes.data, axes.model))
     sr = NamedSharding(mesh, P(axes.data, None))
-    alpha = jax.device_put(jnp.zeros((m, data.n_max), data.x.dtype), sv)
+    alpha = jax.device_put(
+        jnp.zeros(data.mask.shape, data.x.dtype), alpha_sharding(data, mesh, axes)
+    )
     W = jax.device_put(jnp.zeros((m, d), data.x.dtype), sw)
     sigma, omega = omega_mod.init_sigma(m, data.x.dtype)
     return DistributedState(
@@ -557,10 +666,17 @@ def fit_distributed(
     reg = omega_reg.resolve_regularizer(cfg, regularizer, m=raw.m)
     # driver spans (obs): shard / rho / round / objectives / omega_step /
     # result, on the profiler's clock while a profiler session records.
-    # They add no host sync: each readback below was there before.
-    with span("shard", cat="driver"):
+    # They add no host sync: each readback below was there before, but
+    # for the row count of the gauge in shard.
+    layout = raw.layout
+    packed = layout == "packed"
+    with span("shard", cat="driver", layout=layout):
         data, m, d = shard_mtl_data(raw, mesh, axes)
         state = init_state(data, mesh, axes, m, d)
+        if packed and data.workers > 1:
+            rows = worker_layout(np.asarray(raw.n), data.workers)[0]
+            state = dataclasses.replace(state, rows=rows)
+        _count_rows(raw, data, layout)
         objectives, w_from_alpha = make_data_fns(cfg, data)
         state = install_initial_state(
             state, raw, data, m, cfg, mesh, axes, reg, init, w_from_alpha
@@ -586,6 +702,7 @@ def fit_distributed(
         round_fn = make_distributed_round(
             cfg, mesh, axes, m, data.n_max, d, rho,
             structured=isinstance(state.sigma, LowRankDiagSigma),
+            packed=packed,
         )
         # same key schedule as dmtrl.fit/w_step => bit-equal coordinate draws
         key, outer_key = jax.random.split(key)

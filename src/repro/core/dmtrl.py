@@ -31,7 +31,7 @@ from . import omega_regularizers as omega_reg
 from . import sigma_view as sigma_view_mod
 from ..obs.metrics import get_registry
 from .losses import get_loss
-from .mtl_data import MTLData
+from .mtl_data import MTLData, PackedMTLData, refuse_packed
 from .sigma_view import SigmaView
 from .solver_backends import get_backend
 
@@ -337,28 +337,28 @@ def driver_program(name: str):
 
 
 @driver_program("objectives")
-def _objectives_program(lam: float, loss_name: str):
+def _objectives_program(lam: float, loss_name: str, name: str = "objectives"):
     loss = get_loss(loss_name)
 
-    @jax.jit
     def objectives(data, alpha, sigma):
         dd = dual_mod.dual_objective(data, alpha, sigma, lam, loss)
         pp = dual_mod.primal_objective_from_alpha(data, alpha, sigma, lam, loss)
         return dd, pp
 
-    return objectives
+    objectives.__name__ = objectives.__qualname__ = name  # jit_<name> in the trace
+    return jax.jit(objectives)
 
 
 @driver_program("w_from_alpha")
-def _w_from_alpha_program(lam: float):
-    @jax.jit
+def _w_from_alpha_program(lam: float, name: str = "w_from_alpha"):
     def w_from_alpha(data, alpha, sigma):
         return dual_mod.weights_from_alpha(data, alpha, sigma, lam)
 
-    return w_from_alpha
+    w_from_alpha.__name__ = w_from_alpha.__qualname__ = name
+    return jax.jit(w_from_alpha)
 
 
-def make_data_fns(cfg: DMTRLConfig, data: MTLData):
+def make_data_fns(cfg: DMTRLConfig, data: Union[MTLData, PackedMTLData]):
     """Jitted ``objectives(alpha, sigma) -> (dual, primal)`` and
     ``w_from_alpha(alpha, sigma) -> W`` over ``data``.
 
@@ -368,8 +368,11 @@ def make_data_fns(cfg: DMTRLConfig, data: MTLData):
     fit with the same ``(lam, loss)``, so a refit of same-shaped data
     traces nothing.
     """
-    objectives = _objectives_program(cfg.lam, cfg.loss)
-    w_from_alpha = _w_from_alpha_program(cfg.lam)
+    # packed rows get programs of their own names in the device trace
+    # (modules jit_packed_objectives, jit_packed_w_from_alpha)
+    prefix = "packed_" if data.layout == "packed" else ""
+    objectives = _objectives_program(cfg.lam, cfg.loss, prefix + "objectives")
+    w_from_alpha = _w_from_alpha_program(cfg.lam, prefix + "w_from_alpha")
     return (
         lambda alpha, sigma: objectives(data, alpha, sigma),
         lambda alpha, sigma: w_from_alpha(data, alpha, sigma),
@@ -417,6 +420,7 @@ def fit(
     as W(alpha); ``regularizer`` overrides the Omega family member resolved
     from the config (an ``OmegaRegularizer`` instance or name).
     """
+    refuse_packed(data, "the reference engine")
     reg = omega_reg.resolve_regularizer(cfg, regularizer, m=data.m)
     key = jax.random.PRNGKey(cfg.seed)
     m, n_max = data.m, data.n_max

@@ -11,6 +11,12 @@ Notation (paper Thm. 1):
 For W = W(alpha) the regularizer simplifies:
     tr(W Omega W^T) = (1/lambda^2) tr(Sigma B^T B)     (since Sigma Omega Sigma = Sigma)
 so the duality gap never needs Omega explicitly.
+
+The objectives, B and the predictions take either container
+(core/mtl_data.py): their per-task reductions are the container's own
+methods (``task_xt``, ``sum_over_n``, ``predictions``, ``task_sums``). Over
+packed rows those are segment reductions that materialize no padded or
+per-row copy of X or W.
 """
 from __future__ import annotations
 
@@ -25,9 +31,10 @@ Array = jax.Array
 
 
 def compute_B(data: MTLData, alpha: Array) -> Array:
-    """B matrix, columns b_i = (1/n_i) X_i^T alpha_[i].  alpha: (m, n_max)."""
+    """B matrix, columns b_i = (1/n_i) X_i^T alpha_[i].  alpha: (m, n_max),
+    or (R,) over packed rows."""
     masked = alpha * data.mask  # safety: padding contributes nothing
-    b = jnp.einsum("mnd,mn->md", data.x, masked) / data.n[:, None].astype(data.x.dtype)
+    b = data.task_xt(masked) / data.n[:, None].astype(data.x.dtype)
     return b.T  # (d, m)
 
 
@@ -60,7 +67,7 @@ def dual_objective(
     """D(alpha) of Eq. (2)."""
     quad = quad_term(data, alpha, sigma)
     conj = loss.conjugate(-alpha, data.y) * data.mask
-    conj_term = jnp.sum(conj / data.n[:, None].astype(conj.dtype))
+    conj_term = data.sum_over_n(conj)
     return -quad / (2.0 * lam) - conj_term
 
 
@@ -68,8 +75,8 @@ def primal_objective(
     data: MTLData, W: Array, omega: Array, lam: float, loss: Loss
 ) -> Array:
     """P(W) of Eq. (1) with explicit Omega (precision matrix). W: (m, d)."""
-    z = jnp.einsum("mnd,md->mn", data.x, W)
-    emp = jnp.sum(loss.value(z, data.y) * data.mask / data.n[:, None].astype(z.dtype))
+    z = data.predictions(W)
+    emp = data.sum_over_n(loss.value(z, data.y) * data.mask)
     reg = 0.5 * lam * jnp.einsum("id,ij,jd->", W, omega, W)
     return emp + reg
 
@@ -79,8 +86,8 @@ def primal_objective_from_alpha(
 ) -> Array:
     """P(W(alpha)) using tr(W Omega W^T) = tr(Sigma B^T B)/lambda^2."""
     W = weights_from_alpha(data, alpha, sigma, lam)
-    z = jnp.einsum("mnd,md->mn", data.x, W)
-    emp = jnp.sum(loss.value(z, data.y) * data.mask / data.n[:, None].astype(z.dtype))
+    z = data.predictions(W)
+    emp = data.sum_over_n(loss.value(z, data.y) * data.mask)
     reg = quad_term(data, alpha, sigma) / (2.0 * lam)
     return emp + reg
 
@@ -152,8 +159,8 @@ def local_subproblem_objective_full(
 
 
 def predictions(data: MTLData, W: Array) -> Array:
-    """z_j^i = w_i^T x_j^i, (m, n_max)."""
-    return jnp.einsum("mnd,md->mn", data.x, W)
+    """z_j^i = w_i^T x_j^i, (m, n_max); (R,) over packed rows."""
+    return data.predictions(W)
 
 
 def task_scores(W: Array, X: Array, tasks: Array) -> Array:
@@ -169,7 +176,7 @@ def error_rate(data: MTLData, W: Array) -> Array:
     """Masked averaged-over-tasks classification error (paper's metric)."""
     z = predictions(data, W)
     wrong = (jnp.sign(z) != jnp.sign(data.y)).astype(jnp.float32) * data.mask
-    per_task = jnp.sum(wrong, axis=1) / jnp.maximum(jnp.sum(data.mask, axis=1), 1.0)
+    per_task = data.task_sums(wrong) / jnp.maximum(data.task_sums(data.mask), 1.0)
     return jnp.mean(per_task)
 
 

@@ -92,8 +92,14 @@ def _default_mesh(axes: MeshAxes):
 
 
 def _unpad_state(state, raw: MTLData) -> tuple:
-    """(alpha, omega) rows/cols of the REAL tasks from padded mesh state."""
-    alpha = np.asarray(state.alpha)[: raw.m, : raw.n_max]
+    """(alpha, omega) rows/cols of the REAL tasks from padded mesh state;
+    over packed rows, alpha in the raw container's row order."""
+    alpha = np.asarray(state.alpha)
+    if raw.layout == "packed":
+        if state.rows is not None:
+            alpha = alpha[state.rows]
+    else:
+        alpha = alpha[: raw.m, : raw.n_max]
     if state.omega is None:
         omega = None
     elif isinstance(state.omega, SigmaView):

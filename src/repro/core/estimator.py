@@ -41,7 +41,7 @@ from .distributed import DistributedOptions, MeshAxes
 from .dmtrl import DMTRLConfig, WarmStart
 from .engines import Engine, EngineResult, get_engine
 from .losses import get_loss
-from .mtl_data import MTLData
+from .mtl_data import MTLData, PackedMTLData
 from .omega_regularizers import OmegaRegularizer, get_regularizer
 from .sigma_view import SigmaView
 
@@ -241,13 +241,21 @@ class DMTRLEstimator:
         self._model_version += 1
         self._publish_model()
 
-    def fit(self, data: MTLData, track: bool = True) -> "DMTRLEstimator":
-        """Run the full alternating procedure from scratch. Returns self."""
+    def fit(
+        self, data: Union[MTLData, PackedMTLData], track: bool = True
+    ) -> "DMTRLEstimator":
+        """Run the full alternating procedure from scratch. Returns self.
+
+        ``data`` is padded (MTLData) or packed (PackedMTLData) task
+        storage; packed storage trains on the distributed engine, where
+        ``alpha_`` then follows its rows."""
         self.n_fit_calls_ = 0
         self._run(data, init=None, track=track)
         return self
 
-    def partial_fit(self, data: MTLData, track: bool = True) -> "DMTRLEstimator":
+    def partial_fit(
+        self, data: Union[MTLData, PackedMTLData], track: bool = True
+    ) -> "DMTRLEstimator":
         """Continue training from the current (alpha, Sigma) state.
 
         The first call behaves like ``fit``; later calls warm-start every
@@ -290,12 +298,13 @@ class DMTRLEstimator:
     ) -> np.ndarray:
         """Raw scores z = w_task^T x.
 
-        ``X`` may be an MTLData (returns the (m, n_max) masked score matrix)
-        or an (n, d) / (d,) array with ``tasks`` a scalar or (n,) task ids.
+        ``X`` may be an MTLData (returns the (m, n_max) masked score matrix),
+        a PackedMTLData (the (R,) masked scores of its rows) or an (n, d) /
+        (d,) array with ``tasks`` a scalar or (n,) task ids.
         """
         self._check_fitted()
         W = jnp.asarray(self.W_)
-        if isinstance(X, MTLData):
+        if isinstance(X, (MTLData, PackedMTLData)):
             if tasks is not None:
                 raise ValueError(
                     "tasks= only applies to array inputs; an MTLData is "

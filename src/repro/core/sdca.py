@@ -1,8 +1,11 @@
 """Local SDCA (paper Algorithm 2) — naive and block-Gram forms.
 
-Both act on ONE task's (padded) arrays and are vmapped over tasks by the
-driver. Given the task's current dual block ``alpha_i`` and weight vector
-``w_i``, they produce the approximate subproblem solution ``dalpha`` and the
+Both act on ONE task's arrays and are vmapped over tasks by the driver: its
+padded (n_max, d) block, or, with ``offset``, the packed rows of all tasks
+(core/mtl_data.py:PackedMTLData), of which the task's sample j is row
+``offset + j``; ``dalpha`` is then indexed by j and has length ``n_cap``.
+Given the task's current dual variables ``alpha_i`` (all tasks' packed
+ones, with ``offset``) and weight vector ``w_i``, they produce the approximate subproblem solution ``dalpha`` and the
 un-normalized update direction ``r = X_i^T dalpha`` (so that
 ``delta_b_i = eta * r / n_i``).
 
@@ -45,6 +48,15 @@ def sample_coords(key: Array, H: int, n_i: Array, n_max: int) -> Array:
     return jnp.minimum((u * n_i.astype(u.dtype)).astype(jnp.int32), n_i - 1)
 
 
+def _dalpha0(alpha_i, y, offset, n_cap):
+    """Zero dalpha: alpha_i's shape, or (n_cap,) over packed rows. The
+    ``+ y[0] * 0`` gives it the inputs' varying-manual-axes type under
+    shard_map."""
+    if offset is None:
+        return jnp.zeros_like(alpha_i) + y[0] * 0
+    return jnp.zeros((n_cap,), alpha_i.dtype) + y[0] * 0
+
+
 def _psum(x, axis_name):
     return jax.lax.psum(x, axis_name) if axis_name is not None else x
 
@@ -61,6 +73,8 @@ def local_sdca_naive(
     lam: float,
     loss: Loss,
     axis_name: Optional[str] = None,
+    offset: Optional[Array] = None,
+    n_cap: Optional[int] = None,
 ) -> Tuple[Array, Array]:
     """Algorithm 2, one coordinate at a time. Returns (dalpha, r)."""
     nf = jnp.maximum(n_i.astype(x.dtype), 1.0)
@@ -69,21 +83,22 @@ def local_sdca_naive(
     def body(h, carry):
         dalpha, r = carry
         j = coords[h]
-        xj = x[j]
+        row = j if offset is None else offset + j
+        xj = x[row]
         # d-contractions (collective per coordinate when d is sharded)
         wx = _psum(jnp.dot(xj, w_i), axis_name)
         xr = _psum(jnp.dot(xj, r), axis_name)
         xx = _psum(jnp.dot(xj, xj), axis_name)
         c = wx + kappa * xr
         a = kappa * xx
-        atilde = alpha_i[j] + dalpha[j]
-        delta = loss.sdca_delta(atilde, c, a, y[j])
+        atilde = alpha_i[row] + dalpha[j]
+        delta = loss.sdca_delta(atilde, c, a, y[row])
         dalpha = dalpha.at[j].add(delta)
         r = r + delta * xj
         return dalpha, r
 
     H = coords.shape[0]
-    dalpha0 = jnp.zeros_like(alpha_i) + y[0] * 0
+    dalpha0 = _dalpha0(alpha_i, y, offset, n_cap)
     # + x[0]*0 keeps the carry's varying-manual-axes equal to the loop
     # output's under shard_map (x may vary over a 'pod' sample axis)
     r0 = jnp.zeros_like(w_i) + x[0] * 0
@@ -103,6 +118,8 @@ def local_sdca_block(
     loss: Loss,
     block: int = 64,
     axis_name: Optional[str] = None,
+    offset: Optional[Array] = None,
+    n_cap: Optional[int] = None,
 ) -> Tuple[Array, Array]:
     """Block-Gram Local SDCA. Same iterates as naive, MXU-shaped."""
     H = coords.shape[0]
@@ -114,20 +131,22 @@ def local_sdca_block(
 
     def blk_fn(carry, cb):
         dalpha, r = carry
-        xb = x[cb]  # (B, d)
+        rb = cb if offset is None else offset + cb  # the block's rows
+        xb = x[rb]  # (B, d)
         q = _psum(xb @ w_i, axis_name)  # (B,)
         xr = _psum(xb @ r, axis_name)  # (B,)
         G = _psum(xb @ xb.T, axis_name)  # (B, B)
-        yb = y[cb]
+        yb = y[rb]
 
         def inner(k, inner_carry):
             dalpha_, deltas = inner_carry
             j = cb[k]
+            row = j if offset is None else rb[k]
             # c_k = q_k + kappa * (x_k^T r + sum_{k'<k} G[k,k'] delta_k')
             corr = jnp.dot(G[k], deltas)  # deltas[k:] are still 0
             c = q[k] + kappa * (xr[k] + corr)
             a = kappa * G[k, k]
-            atilde = alpha_i[j] + dalpha_[j]
+            atilde = alpha_i[row] + dalpha_[j]
             delta = loss.sdca_delta(atilde, c, a, yb[k])
             dalpha_ = dalpha_.at[j].add(delta)
             deltas = deltas.at[k].set(delta)
@@ -140,7 +159,7 @@ def local_sdca_block(
         r = r + xb.T @ deltas
         return (dalpha, r), None
 
-    dalpha0 = jnp.zeros_like(alpha_i) + y[0] * 0
+    dalpha0 = _dalpha0(alpha_i, y, offset, n_cap)
     r0 = jnp.zeros_like(w_i) + x[0] * 0  # see local_sdca_naive note
     (dalpha, r), _ = jax.lax.scan(blk_fn, (dalpha0, r0), coords_b)
     return dalpha, r

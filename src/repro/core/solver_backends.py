@@ -30,6 +30,14 @@ The Pallas backends cover only the losses with a closed-form kernel delta
 built; ``pallas_round`` also refuses a task block over its VMEM budget
 (``kernels.sdca.sdca_kernel.ROUND_VMEM_BUDGET``) when it is traced. Neither
 runs a jnp path in the kernel's place.
+
+``naive`` and ``block_gram`` also run on packed task storage
+(``packed=True``): ``make(..., n_cap=n_max)`` builds
+``solve(x, y, alpha, w_i, n_i, sigma_ii, key, offset)`` over all tasks'
+packed rows, reading sample j of the task at row ``offset + j``, with the
+same coordinate draws. The Pallas backends run their kernels over a
+task's padded rows and refuse packed data
+(core/distributed.py:make_local_solve).
 """
 from __future__ import annotations
 
@@ -70,6 +78,9 @@ class SolverBackend:
     # solve body contains pallas_call ops: shard_map engines must disable
     # replication checking around it (distributed.round_shard_map)
     uses_pallas: bool = False
+    # runs on packed task storage (core/mtl_data.py:PackedMTLData): make
+    # takes ``n_cap`` and solve a trailing ``offset``, the task's first row
+    packed: bool = False
 
     def round_local_iters(self, H: int, block: int) -> int:
         """Round H up to this backend's alignment requirement."""
@@ -128,11 +139,13 @@ def _make_naive(
     H: int,
     block: int = 64,
     axis_name: Optional[str] = None,
+    n_cap: Optional[int] = None,
 ) -> Solver:
-    def solve(x, y, alpha_i, w_i, n_i, sigma_ii, key):
+    def solve(x, y, alpha_i, w_i, n_i, sigma_ii, key, offset=None):
         coords = sample_coords(key, H, n_i, x.shape[0])
         return local_sdca_naive(
-            x, y, alpha_i, w_i, n_i, sigma_ii, coords, rho, lam, loss, axis_name
+            x, y, alpha_i, w_i, n_i, sigma_ii, coords, rho, lam, loss, axis_name,
+            offset=offset, n_cap=n_cap,
         )
 
     return solve
@@ -148,12 +161,13 @@ def _make_block_gram(
     H: int,
     block: int = 64,
     axis_name: Optional[str] = None,
+    n_cap: Optional[int] = None,
 ) -> Solver:
-    def solve(x, y, alpha_i, w_i, n_i, sigma_ii, key):
+    def solve(x, y, alpha_i, w_i, n_i, sigma_ii, key, offset=None):
         coords = sample_coords(key, H, n_i, x.shape[0])
         return local_sdca_block(
             x, y, alpha_i, w_i, n_i, sigma_ii, coords, rho, lam, loss,
-            block=block, axis_name=axis_name,
+            block=block, axis_name=axis_name, offset=offset, n_cap=n_cap,
         )
 
     return solve
@@ -241,6 +255,7 @@ register_backend(
         block_aligned=False,
         supports_sharded_features=True,
         make=_make_naive,
+        packed=True,
     )
 )
 register_backend(
@@ -251,6 +266,7 @@ register_backend(
         block_aligned=True,
         supports_sharded_features=True,
         make=_make_block_gram,
+        packed=True,
     )
 )
 register_backend(
