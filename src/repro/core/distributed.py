@@ -249,7 +249,9 @@ def make_local_solve(
         # local valid sample count in this pod's contiguous slice
         n_local = jnp.clip(n - pi * n_loc, 0, n_loc).astype(jnp.int32)
         if use_gram:
-            from .sdca import sample_coords, sdca_block_solve, sdca_gram_solve
+            from .sdca import (
+                add_block, sample_coords, sdca_block_solve, sdca_gram_solve,
+            )
 
             coords = jax.vmap(
                 lambda nn, kk: sample_coords(kk, H, nn, x.shape[1])
@@ -286,11 +288,12 @@ def make_local_solve(
                         ),
                         axes.model,
                     )
-                    dalpha, deltas = jax.vmap(
-                        lambda Gm, qm, xrm, dam, am, ym, cm, km: sdca_block_solve(
-                            Gm, qm, xrm, dam, am, ym, cm, km, loss
-                        )
-                    )(G, q, xr, dalpha, alpha, y, cb, kap)
+                    take = lambda v: jnp.take_along_axis(v, cb, axis=1)
+                    at0 = take(alpha) + take(dalpha)  # (m_loc, B)
+                    deltas = jax.vmap(lambda *a: sdca_block_solve(*a, loss))(
+                        G, q, xr, at0, take(y), cb, kap
+                    )
+                    dalpha = jax.vmap(add_block)(dalpha, cb, deltas)
                     r = r + jnp.einsum("mbd,mb->md", Xb, deltas)
                     return (dalpha, r), None
 

@@ -18,7 +18,9 @@ block_gram : TPU adaptation (see docs/DESIGN.md §4). H steps are processed in
              Gram block only. Produces the *exact same iterate sequence* as
              naive for the same sampled coordinate order (duplicates within a
              block included), because inner products are corrected
-             incrementally through G.
+             incrementally through G and a repeated coordinate's dual
+             through a (B, B) same-coordinate mask; dalpha is gathered
+             once and scattered once per block.
 
 Engines do not call these functions directly: they resolve a named backend
 through ``repro.core.solver_backends`` (docs/DESIGN.md §5), which wraps the
@@ -136,26 +138,12 @@ def local_sdca_block(
         q = _psum(xb @ w_i, axis_name)  # (B,)
         xr = _psum(xb @ r, axis_name)  # (B,)
         G = _psum(xb @ xb.T, axis_name)  # (B, B)
-        yb = y[rb]
-
-        def inner(k, inner_carry):
-            dalpha_, deltas = inner_carry
-            j = cb[k]
-            row = j if offset is None else rb[k]
-            # c_k = q_k + kappa * (x_k^T r + sum_{k'<k} G[k,k'] delta_k')
-            corr = jnp.dot(G[k], deltas)  # deltas[k:] are still 0
-            c = q[k] + kappa * (xr[k] + corr)
-            a = kappa * G[k, k]
-            atilde = alpha_i[row] + dalpha_[j]
-            delta = loss.sdca_delta(atilde, c, a, yb[k])
-            dalpha_ = dalpha_.at[j].add(delta)
-            deltas = deltas.at[k].set(delta)
-            return dalpha_, deltas
-
-        # derive from q so the carry carries the same varying-manual-axes
-        # type as the inputs under shard_map
-        deltas0 = q * 0.0
-        dalpha, deltas = jax.lax.fori_loop(0, block, inner, (dalpha, deltas0))
+        # the block's starting duals, gathered once; the recursion folds
+        # in earlier draws of the same coordinate, and dalpha takes the
+        # block's deltas in one scatter
+        at0 = alpha_i[rb] + dalpha[cb]
+        deltas = sdca_block_solve(G, q, xr, at0, y[rb], cb, kappa, loss)
+        dalpha = add_block(dalpha, cb, deltas)
         r = r + xb.T @ deltas
         return (dalpha, r), None
 
@@ -163,6 +151,18 @@ def local_sdca_block(
     r0 = jnp.zeros_like(w_i) + x[0] * 0  # see local_sdca_naive note
     (dalpha, r), _ = jax.lax.scan(blk_fn, (dalpha0, r0), coords_b)
     return dalpha, r
+
+
+def add_block(dalpha: Array, cb: Array, deltas: Array) -> Array:
+    """``dalpha`` with one block's deltas added at its coords ``cb``.
+
+    Every draw of a coordinate writes the same value, its dalpha plus all
+    of the block's deltas for it, so the result does not depend on the
+    order in which the scatter meets repeated indices, which XLA leaves
+    open: two programs running the same round agree bit for bit."""
+    same = cb[:, None] == cb[None, :]
+    total = jnp.sum(jnp.where(same, deltas[None, :], 0.0), axis=1)
+    return dalpha.at[cb].set(dalpha[cb] + total)
 
 
 def sdca_gram_solve(
@@ -239,26 +239,30 @@ def sdca_block_solve(
     G: Array,  # (B, B) Gram of this block's rows (psum'ed)
     q: Array,  # (B,)   X_blk @ w (psum'ed)
     xr: Array,  # (B,)   X_blk @ r_prev (psum'ed)
-    dalpha: Array,
-    alpha_i: Array,
-    y: Array,
-    cb: Array,  # (B,) coords of this block
+    at0: Array,  # (B,)   alpha + dalpha at the block's coords, block start
+    yb: Array,  # (B,)   labels of the block's rows
+    cb: Array,  # (B,)   coords of this block
     kappa: Array,
     loss: Loss,
-) -> Tuple[Array, Array]:
-    """Collective-free scalar recursion for ONE block (hoisted-psum form).
-    Returns (dalpha, deltas)."""
-    B = cb.shape[0]
+) -> Array:
+    """Collective-free B-step scalar recursion of ONE block. Returns the
+    block's deltas; the caller adds them into dalpha with ``add_block``.
 
-    def body(k, carry):
-        dalpha_, deltas = carry
-        corr = jnp.dot(G[k], deltas)
+    Step k's dual is ``at0[k]`` plus the deltas of earlier steps that drew
+    the same coordinate (``deltas[k:]`` are still 0), so only (B,) arrays
+    ride in the loop, as in the Pallas kernel (kernels/sdca)."""
+    B = cb.shape[0]
+    same = (cb[:, None] == cb[None, :]).astype(q.dtype)  # (B, B)
+
+    def body(k, deltas):
+        # c_k = q_k + kappa * (x_k^T r + sum_{k'<k} G[k,k'] delta_k')
+        corr = jnp.dot(G[k], deltas)  # deltas[k:] are still 0
         c = q[k] + kappa * (xr[k] + corr)
         a = kappa * G[k, k]
-        j = cb[k]
-        atilde = alpha_i[j] + dalpha_[j]
-        delta = loss.sdca_delta(atilde, c, a, y[j])
-        return dalpha_.at[j].add(delta), deltas.at[k].set(delta)
+        atilde = at0[k] + jnp.sum(same[k] * deltas)
+        delta = loss.sdca_delta(atilde, c, a, yb[k])
+        return deltas.at[k].set(delta)
 
-    deltas0 = q * 0.0
-    return jax.lax.fori_loop(0, B, body, (dalpha, deltas0))
+    # derive from q so the carry carries the same varying-manual-axes
+    # type as the inputs under shard_map
+    return jax.lax.fori_loop(0, B, body, q * 0.0)

@@ -296,6 +296,42 @@ def test_model_axis_hoisted_block_gram_exact():
     assert r["serr"] < 5e-5, r
 
 
+def test_model_axis_hoisted_block_gram_equals_naive_on_one_device(small_problem):
+    """The hoisted block-Gram round (a ``model`` axis, here of size 1) gives
+    naive's dual variables: 128 draws a round in blocks of 64 from tasks of
+    about 40 samples, so most blocks draw a coordinate several times."""
+    import jax
+
+    from repro.core import DMTRLConfig
+    from repro.core.distributed import init_state, make_distributed_round, shard_mtl_data
+    from repro.launch.mesh import make_mesh
+
+    base = dict(loss="hinge", lam=1e-3, local_iters=128, block_size=64)
+    runs = (
+        (DMTRLConfig(solver="naive", **base), make_mesh((1,), ("data",)), MeshAxes(data="data")),
+        (
+            DMTRLConfig(solver="block_gram", dist_block_hoisted=True, **base),
+            make_mesh((1, 1), ("data", "model")),
+            MeshAxes(data="data", model="model"),
+        ),
+    )
+    out = []
+    for cfg, mesh, axes in runs:
+        data, m, d = shard_mtl_data(small_problem.train, mesh, axes)
+        st = init_state(data, mesh, axes, m, d)
+        rf = make_distributed_round(cfg, mesh, axes, m, data.n_max, d, 2.0)
+        alpha, W = st.alpha, st.W
+        for t in range(2):
+            key = jax.random.PRNGKey(7 + t)
+            alpha, W = rf(data.x, data.y, data.mask, data.n, alpha, W, st.sigma, key)
+        out.append((np.asarray(alpha), np.asarray(W)))
+    (a_naive, w_naive), (a_hoist, w_hoist) = out
+    assert int(np.max(small_problem.train.n)) < 64
+    np.testing.assert_allclose(a_hoist, a_naive, atol=2e-5)
+    np.testing.assert_allclose(w_hoist, w_naive, atol=2e-5)
+    assert np.abs(a_naive).max() > 1e-3  # the rounds moved
+
+
 @pytest.mark.slow
 def test_pod_axis_converges():
     """intra-task sample partitioning over 'pod': iterates differ from the

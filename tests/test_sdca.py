@@ -35,16 +35,92 @@ def _args(data, i, loss, key, H=96):
     )
 
 
+def _dup_args(layout, n_i, loss, key, H=192, n_cap=64, d=30):
+    """One task of ``n_i`` samples (3, 17 or 60 at B = 64: most blocks draw
+    a coordinate several times), padded to ``n_cap`` rows or packed between
+    tasks of 5 and 11 samples (offset 5), with feasible nonzero duals."""
+    sizes = (n_i,) if layout == "padded" else (5, n_i, 11)
+    rows = n_cap if layout == "padded" else sum(sizes)
+    kx, ky, ka, kv, kc = jax.random.split(key, 5)
+    x = jax.random.normal(kx, (rows, d))
+    x = x / jnp.linalg.norm(x, axis=1, keepdims=True)
+    y = jnp.where(jax.random.normal(ky, (rows,)) > 0, 1.0, -1.0)
+    alpha = y * jax.random.uniform(ka, (rows,), minval=0.05, maxval=0.45)
+    w = 0.3 * jax.random.normal(kv, (d,))
+    n = jnp.int32(n_i)
+    coords = sample_coords(kc, H, n, n_cap)
+    args = (x, y, alpha, w, n, jnp.float32(0.25), coords, 2.0, 1e-3, loss)
+    kw = {} if layout == "padded" else {"offset": jnp.int32(5), "n_cap": n_cap}
+    return args, kw
+
+
+# block sizes over the fixture's task 1, then heavy duplicates at B = 64
+_BLOCK_CASES = [pytest.param((b, None, None), id=str(b)) for b in (16, 32, 96)] + [
+    pytest.param((64, layout, n_i), id=f"{layout}-n{n_i}")
+    for layout in ("padded", "packed")
+    for n_i in (3, 17, 60)
+]
+
+
 @pytest.mark.parametrize("loss_name", sorted(registered_losses()))
-@pytest.mark.parametrize("block", [16, 32, 96])
+@pytest.mark.parametrize("block", _BLOCK_CASES)
 def test_block_equals_naive(data, loss_name, block):
+    block, layout, n_i = block
     loss = get_loss(loss_name)
     key = jax.random.PRNGKey(11)
-    args = _args(data, 1, loss, key)
-    da1, r1 = local_sdca_naive(*args)
-    da2, r2 = local_sdca_block(*args, block=block)
+    if layout is None:
+        args, kw = _args(data, 1, loss, key), {}
+    else:
+        args, kw = _dup_args(layout, n_i, loss, key)
+        dup = np.asarray(args[6]).reshape(-1, block)
+        assert all(len(set(cb)) < block for cb in dup)  # every block repeats
+    da1, r1 = local_sdca_naive(*args, **kw)
+    da2, r2 = local_sdca_block(*args, block=block, **kw)
     np.testing.assert_allclose(np.asarray(da1), np.asarray(da2), atol=2e-5)
     np.testing.assert_allclose(np.asarray(r1), np.asarray(r2), atol=2e-5)
+    assert np.abs(np.asarray(da1)).max() > 1e-3  # the round moved
+
+
+def _nested_loop_carries(jaxpr, depth=0):
+    """Avals carried by every scan or while loop nested inside another."""
+    out = []
+    for eqn in jaxpr.eqns:
+        p = eqn.params
+        carry = None
+        if eqn.primitive.name == "scan":
+            lo = p["num_consts"]
+            carry = p["jaxpr"].in_avals[lo : lo + p["num_carry"]]
+        elif eqn.primitive.name == "while":
+            carry = p["body_jaxpr"].in_avals[p["body_nconsts"] :]
+        if carry is not None and depth >= 1:
+            out += list(carry)
+        for v in p.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    out += _nested_loop_carries(sub, depth + (carry is not None))
+    return out
+
+
+@pytest.mark.parametrize("tasks", [1, 3])
+@pytest.mark.parametrize("layout", ["padded", "packed"])
+def test_block_recursion_carries_no_sample_axis(layout, tasks):
+    """The B-step recursion carries only the block's (B,) deltas (per task):
+    dalpha is gathered before it and scatter-added after it, never carried,
+    so no step reads or writes a task's whole dual vector."""
+    B, n_cap = 64, 200
+    args, kw = _dup_args(layout, 17, get_loss("hinge"), jax.random.PRNGKey(3), n_cap=n_cap)
+    solve = lambda x, y, a, w, n, s, c: local_sdca_block(
+        x, y, a, w, n, s, c, *args[7:], block=B, **kw
+    )
+    if tasks > 1:  # vmapped over tasks, as the engines run it
+        batched = tuple(jnp.stack([v] * tasks) for v in args[:7])
+        solve = jax.vmap(solve)
+    else:
+        batched = args[:7]
+    carries = _nested_loop_carries(jax.make_jaxpr(solve)(*batched).jaxpr)
+    assert carries, "no loop nested in the block scan"
+    assert all(a.size <= tasks * B for a in carries), [a.str_short() for a in carries]
 
 
 @pytest.mark.parametrize("loss_name", ["hinge", "squared", "smoothed_hinge"])
