@@ -18,7 +18,7 @@ import sys
 
 
 def run(n_dev: int, taus, straggler: int, seed: int = 0):
-    from repro.core import DMTRLConfig, MeshAxes
+    from repro.core import AsyncOptions, DMTRLConfig, MeshAxes
     from repro.core.async_dmtrl import fit_async
     from repro.core.distributed import fit_distributed
     from repro.launch.mesh import make_mesh
@@ -49,8 +49,9 @@ def run(n_dev: int, taus, straggler: int, seed: int = 0):
         }
     ]
     for tau in taus:
-        cfg = DMTRLConfig(**base, tau=tau, async_delays=delays)
-        _, _, _, h = fit_async(cfg, sp.train, mesh, ax)
+        opts = AsyncOptions(tau=tau, async_delays=delays)
+        _, _, _, h = fit_async(DMTRLConfig(**base), sp.train, mesh, ax,
+                               options=opts)
         ticks, gaps = cv.effective_gap_curve(h)
         s = cv.staleness_summary(h)
         rows.append(

@@ -111,23 +111,22 @@ def run_traced(n_workers: int = 4, straggler: int = 4, tiny: bool = False,
         1, m=n_workers, d=16 if tiny else 32,
         n_train_avg=40 if tiny else 80, n_test_avg=10, seed=2,
     )
-    cfg = AsyncOptions(
+    opts = AsyncOptions(
         tau=2,
         async_delays=(1,) * (n_workers - 1) + (straggler,),
         transport="threaded",
         n_workers=n_workers,
-    ).merge_into(
-        DMTRLConfig(
-            loss="hinge", lam=1e-4,
-            outer_iters=2, rounds=3 if tiny else 6,
-            local_iters=32 if tiny else 64,
-            solver="block_gram", block_size=32, seed=0,
-            track_every=10**6,
-        )
+    )
+    cfg = DMTRLConfig(
+        loss="hinge", lam=1e-4,
+        outer_iters=2, rounds=3 if tiny else 6,
+        local_iters=32 if tiny else 64,
+        solver="block_gram", block_size=32, seed=0,
+        track_every=10**6,
     )
     tracer = obs.enable(clear=True)
     try:
-        fit_async(cfg, sp.train, None, MeshAxes(), options=None)
+        fit_async(cfg, sp.train, None, MeshAxes(), options=opts)
     finally:
         obs.disable()
     if trace_path is None:

@@ -112,14 +112,15 @@ def run_codec_one(transport: str, topology, codec: str, n_workers: int,
     from repro.core.transport import get_transport
 
     sp = _problem(n_workers, tiny)
-    cfg = AsyncOptions(
+    opts = AsyncOptions(
         tau=0, transport=transport, n_workers=n_workers,
         topology=topology, codec=codec,
-    ).merge_into(_config(tiny, seed))
+    )
+    cfg = _config(tiny, seed)
     reg = omega_reg.resolve_regularizer(cfg, None, m=sp.train.m)
     t = get_transport(transport).factory()
     t.setup(cfg, sp.train, mesh=None, axes=MeshAxes(), reg=reg,
-            init=None, track=True)
+            init=None, track=True, options=opts)
     t0 = time.perf_counter()
     try:
         key = jax.random.PRNGKey(cfg.seed)

@@ -6,12 +6,9 @@ The supported training surface is the engine-agnostic facade:
     est = DMTRLEstimator(engine="distributed", mesh=mesh, loss="hinge")
     est.fit(train).score(test)
 
-``fit`` / ``fit_distributed`` / ``fit_async`` remain importable as thin
-deprecated wrappers over the same engine implementations.
+Each engine's own function lives in its module: ``core.dmtrl.fit``,
+``core.distributed.fit_distributed`` and ``core.async_dmtrl.fit_async``.
 """
-import functools as _functools
-import warnings as _warnings
-
 from .dmtrl import (
     DMTRLConfig,
     DMTRLResult,
@@ -19,17 +16,13 @@ from .dmtrl import (
     w_step,
     make_w_step_round,
 )
-from .dmtrl import fit as _fit_impl
 from .distributed import (
-    DistributedOptions,
     MeshAxes,
     make_distributed_round,
     make_local_solve,
     server_reduce,
 )
-from .distributed import fit_distributed as _fit_distributed_impl
 from .async_dmtrl import AsyncOptions, make_async_tick
-from .async_dmtrl import fit_async as _fit_async_impl
 from .transport import (
     CommitReceipt,
     Snapshot,
@@ -113,50 +106,19 @@ from . import (
 from . import transport  # noqa: F401 (registry module, part of the API)
 
 
-def _deprecated(fn, replacement: str):
-    @_functools.wraps(fn)
-    def wrapper(*args, **kwargs):
-        _warnings.warn(
-            f"repro.core.{fn.__name__} is deprecated; use {replacement} "
-            "(see docs/DESIGN.md §8 for the migration table)",
-            DeprecationWarning,
-            stacklevel=2,
-        )
-        return fn(*args, **kwargs)
-
-    wrapper.__doc__ = (
-        f"Deprecated: use {replacement}.\n\n{fn.__doc__ or ''}"
-    )
-    return wrapper
-
-
-fit = _deprecated(_fit_impl, 'DMTRLEstimator(engine="reference").fit')
-fit_distributed = _deprecated(
-    _fit_distributed_impl, 'DMTRLEstimator(engine="distributed", mesh=...).fit'
-)
-fit_async = _deprecated(
-    _fit_async_impl,
-    'DMTRLEstimator(engine="async", mesh=..., '
-    "async_options=AsyncOptions(...)).fit",
-)
-
 __all__ = [
     "DMTRLConfig",
     "DMTRLResult",
     "DMTRLEstimator",
     "NotFittedError",
     "WarmStart",
-    "fit",
     "w_step",
     "make_w_step_round",
     "MeshAxes",
-    "DistributedOptions",
     "AsyncOptions",
-    "fit_distributed",
     "make_distributed_round",
     "make_local_solve",
     "server_reduce",
-    "fit_async",
     "make_async_tick",
     "Transport",
     "TransportSpec",

@@ -17,7 +17,8 @@ alternation:
         Sigma, Omega <- regularizer.step(W)          # Omega-step
         transport.install_sigma(...)                 # maybe overlapped
 
-over a pluggable ``core.transport`` member (``AsyncOptions.transport``):
+over a pluggable ``core.transport`` member (``AsyncOptions.transport``;
+every staleness knob below is a field of ``AsyncOptions``):
 
   simulated     deterministic per-worker clock simulation, fused masked
                 SPMD commits — bit-reproducible; the default and the
@@ -63,14 +64,16 @@ differs).
 from __future__ import annotations
 
 import dataclasses
+import numbers
 from typing import Optional, Tuple, Union
 
 import jax
+import numpy as np
 from jax.sharding import Mesh
 
 from . import omega_regularizers as omega_reg
 from .distributed import MeshAxes
-from .dmtrl import DMTRLConfig, WarmStart, _rho_value, validate_async_fields
+from .dmtrl import DMTRLConfig, WarmStart, _rho_value
 from .mtl_data import MTLData, refuse_packed
 from .transport import (  # re-exported for backward compatibility
     _adapt_tau,
@@ -78,6 +81,7 @@ from .transport import (  # re-exported for backward compatibility
     get_transport,
     make_async_tick,
 )
+from .wire import available_codecs
 from ..obs.metrics import publish_wire_stats
 from ..obs.trace import span
 
@@ -92,10 +96,108 @@ __all__ = [
 ]
 
 
+def _validate_tau(tau) -> None:
+    if tau == "auto":
+        return
+    if not isinstance(tau, int) or isinstance(tau, bool):
+        raise ValueError(f'tau must be an int >= 0 or "auto", got {tau!r}')
+    if tau < 0:
+        raise ValueError(f"tau must be >= 0, got {tau}")
+
+
+def _validate_topology(topology) -> None:
+    """Named topologies are checked against the known set; an explicit
+    adjacency must be a square symmetric 0/1 matrix (connectivity is
+    checked at transport setup, where the worker count is known)."""
+    if isinstance(topology, str):
+        if topology not in ("ring", "torus", "complete"):
+            raise ValueError(
+                f"topology must be 'ring' | 'torus' | 'complete' or an "
+                f"explicit adjacency matrix, got {topology!r}"
+            )
+        return
+    adj = np.asarray(topology)
+    if adj.ndim != 2 or adj.shape[0] != adj.shape[1] or adj.shape[0] < 1:
+        raise ValueError(
+            f"adjacency topology must be a square matrix, got shape "
+            f"{adj.shape}"
+        )
+    if not np.array_equal(adj, adj.T):
+        raise ValueError("adjacency topology must be symmetric")
+    if not np.isin(adj, (0, 1)).all():
+        raise ValueError("adjacency topology entries must be 0/1")
+
+
+def validate_async_fields(opts: "AsyncOptions") -> None:
+    """Eager validation of the async knobs, so that a malformed value
+    (e.g. tau="fast") raises at construction, not mid-fit."""
+    _validate_tau(opts.tau)
+    if not isinstance(opts.transport, str):
+        raise ValueError(
+            f"transport must be a core.transport member name, got "
+            f"{opts.transport!r}"
+        )
+    _validate_topology(opts.topology)
+    if not isinstance(opts.codec, str):
+        raise ValueError(
+            f"codec must be a core.wire codec name, got {opts.codec!r}"
+        )
+    if opts.codec not in available_codecs():
+        raise ValueError(
+            f"unknown wire codec {opts.codec!r}; have "
+            f"{sorted(available_codecs())}"
+        )
+    n_workers, budget = opts.n_workers, opts.staleness_budget
+    if n_workers is not None and (
+        not isinstance(n_workers, numbers.Integral)
+        or isinstance(n_workers, bool)
+        or n_workers < 1
+    ):
+        raise ValueError(f"n_workers must be an int >= 1 or None, got {n_workers!r}")
+    if budget is not None and (
+        isinstance(budget, bool)
+        or not isinstance(budget, numbers.Real)
+        or budget < 0
+    ):
+        raise ValueError(
+            f"staleness_budget must be a float >= 0 or None, got {budget!r}"
+        )
+    if budget is not None and opts.tau != "auto":
+        raise ValueError(
+            f'staleness_budget only drives the tau="auto" controller; it '
+            f"would be silently ignored with tau={opts.tau!r}"
+        )
+    tau_max = opts.tau_max
+    if not isinstance(tau_max, int) or isinstance(tau_max, bool) or tau_max < 0:
+        raise ValueError(f"tau_max must be an int >= 0, got {tau_max!r}")
+    omega_delay = opts.omega_delay
+    if (
+        not isinstance(omega_delay, int)
+        or isinstance(omega_delay, bool)
+        or omega_delay < 0
+    ):
+        raise ValueError(f"omega_delay must be an int >= 0, got {omega_delay!r}")
+    if opts.async_delays is not None:
+        # numbers.Integral admits numpy ints (delay schedules are often
+        # built from numpy arrays); _worker_delays coerces them with int()
+        bad = [
+            v
+            for v in opts.async_delays
+            if not isinstance(v, numbers.Integral)
+            or isinstance(v, bool)
+            or v < 1
+        ]
+        if bad:
+            raise ValueError(
+                f"async_delays entries must be ints >= 1, got "
+                f"{opts.async_delays!r}"
+            )
+
+
 @dataclasses.dataclass(frozen=True)
 class AsyncOptions:
-    """Staleness knobs of the async engine, split out of the legacy
-    kitchen-sink config (the new home of ``DMTRLConfig.tau`` & friends).
+    """Staleness knobs of the async engine: the one home of ``tau`` and
+    its friends, read by the transports (``Transport.setup``).
 
     Validation is eager: ``AsyncOptions(tau="fast")`` raises at
     construction with a clear message, not mid-fit.
@@ -107,12 +209,16 @@ class AsyncOptions:
     size (``simulated`` always derives workers from the mesh).
     """
 
-    tau: Union[int, str] = 0  # SSP staleness bound; "auto" adapts online
+    tau: Union[int, str] = 0  # SSP staleness bound: a worker may run at
+    #               most tau rounds ahead of the slowest worker (0 == bulk-
+    #               synchronous); "auto" adapts the bound online from the
+    #               observed staleness histogram (transport._adapt_tau)
     tau_max: int = 8  # clamp for the tau="auto" controller
     async_delays: Optional[Tuple[int, ...]] = None  # simulated per-worker
     #               solve ticks; None == homogeneous workers (host
     #               transports turn them into sleep pacing)
     omega_delay: int = 0  # server commits the Sigma install may lag behind
+    #               (0 == barrier, same as sync)
     transport: str = "simulated"  # core.transport member name
     n_workers: Optional[int] = None  # host-transport worker count
     staleness_budget: Optional[float] = None  # tau="auto" cost target:
@@ -123,31 +229,7 @@ class AsyncOptions:
     #               ("none" | "bf16" | "int8"; core.wire registry)
 
     def __post_init__(self):
-        validate_async_fields(
-            self.tau,
-            self.tau_max,
-            self.async_delays,
-            self.omega_delay,
-            transport=self.transport,
-            n_workers=self.n_workers,
-            staleness_budget=self.staleness_budget,
-            topology=self.topology,
-            codec=self.codec,
-        )
-
-    def merge_into(self, cfg: DMTRLConfig) -> DMTRLConfig:
-        return dataclasses.replace(
-            cfg,
-            tau=self.tau,
-            tau_max=self.tau_max,
-            async_delays=self.async_delays,
-            omega_delay=self.omega_delay,
-            transport=self.transport,
-            n_workers=self.n_workers,
-            staleness_budget=self.staleness_budget,
-            topology=self.topology,
-            codec=self.codec,
-        )
+        validate_async_fields(self)
 
 
 def fit_async(
@@ -167,8 +249,8 @@ def fit_async(
     The history additionally carries per-commit staleness events and the
     transport clock of every objective sample.
 
-    ``options`` (AsyncOptions) overrides the legacy staleness fields of the
-    config — including ``transport=`` which picks the execution substrate;
+    ``options`` (AsyncOptions, its defaults when None) holds the staleness
+    knobs, ``transport=`` among them, which picks the execution substrate;
     ``init`` warm-starts from raw-shaped (alpha, sigma, omega);
     ``regularizer`` overrides the Omega family member. ``mesh`` is required
     by the ``simulated`` transport and optional for the host transports
@@ -177,31 +259,18 @@ def fit_async(
     refuse_packed(raw, "the async engine")
     if axes is None:
         axes = MeshAxes()
-    if options is not None:
-        cfg = options.merge_into(cfg)
-    # cfg may predate the eager __post_init__ validation (e.g. built via
-    # dataclasses.replace on old pickles); keep the fit-time check too.
-    validate_async_fields(
-        cfg.tau,
-        cfg.tau_max,
-        cfg.async_delays,
-        cfg.omega_delay,
-        transport=cfg.transport,
-        n_workers=cfg.n_workers,
-        staleness_budget=cfg.staleness_budget,
-        topology=getattr(cfg, "topology", "complete"),
-        codec=getattr(cfg, "codec", "none"),
-    )
+    opts = options or AsyncOptions()
     reg = omega_reg.resolve_regularizer(cfg, regularizer, m=raw.m)
     # root span + sequential driver-phase spans: "setup" / per-outer
     # "w_step" / "omega_step" / "result" tile "fit_async" almost exactly,
     # which is what bench_obs's breakdown-sums-to-total check leans on
-    with span("fit_async", cat="driver", transport=cfg.transport):
-        with span("setup", cat="driver", transport=cfg.transport):
-            spec = get_transport(cfg.transport)
+    with span("fit_async", cat="driver", transport=opts.transport):
+        with span("setup", cat="driver", transport=opts.transport):
+            spec = get_transport(opts.transport)
             transport = spec.factory()
             transport.setup(
-                cfg, raw, mesh=mesh, axes=axes, reg=reg, init=init, track=track
+                cfg, raw, mesh=mesh, axes=axes, reg=reg, init=init,
+                track=track, options=opts,
             )
         key = jax.random.PRNGKey(cfg.seed)
         # rho always sees the NEWEST Sigma, installed or pending (a pending
@@ -224,14 +293,14 @@ def fit_async(
                         # overlapped Omega-step: defer the install into the
                         # next W-step except at the end (the last Sigma must
                         # land now)
-                        defer = cfg.omega_delay > 0 and p < cfg.outer_iters - 1
+                        defer = opts.omega_delay > 0 and p < cfg.outer_iters - 1
                         transport.install_sigma(sig, om, defer=defer)
                         rho_sigma = sig
-            with span("result", cat="driver", transport=cfg.transport):
+            with span("result", cat="driver", transport=opts.transport):
                 out = transport.result()
                 ws = getattr(transport, "wire_stats", None)
                 if ws is not None:
-                    publish_wire_stats(ws, transport=cfg.transport)
+                    publish_wire_stats(ws, transport=opts.transport)
             return out
         finally:
             transport.close()

@@ -14,7 +14,6 @@
 """
 from __future__ import annotations
 
-import dataclasses
 from typing import Dict, Tuple
 
 import jax
@@ -23,6 +22,7 @@ import numpy as np
 
 from . import dual as dual_mod
 from . import omega as omega_mod
+from . import omega_regularizers as omega_reg
 from .dmtrl import DMTRLConfig, DMTRLResult, fit as dmtrl_fit
 from .losses import get_loss
 from .mtl_data import MTLData
@@ -34,8 +34,7 @@ Array = jax.Array
 # STL
 # ---------------------------------------------------------------------------
 def fit_stl(cfg: DMTRLConfig, data: MTLData) -> DMTRLResult:
-    stl_cfg = dataclasses.replace(cfg, learn_omega=False)
-    return dmtrl_fit(stl_cfg, data)
+    return dmtrl_fit(cfg, data, regularizer="identity_stl")
 
 
 # ---------------------------------------------------------------------------
@@ -59,6 +58,7 @@ def fit_centralized_mtrl(
     place of hinge for the central baseline, as subgradient FISTA has no
     guarantee). Returns (W, sigma, history)."""
     loss = get_loss(cfg.loss)
+    learns = omega_reg.resolve_regularizer(cfg).learns
     m, d = data.m, data.d
     W = jnp.zeros((m, d), data.x.dtype)
     sigma, omega = omega_mod.init_sigma(m, data.x.dtype)
@@ -93,7 +93,7 @@ def fit_centralized_mtrl(
         hist["primal"].append(
             float(dual_mod.primal_objective(data, W, omega, cfg.lam, loss))
         )
-        if cfg.learn_omega:
+        if learns:
             sigma, omega = omega_mod.omega_step(W, cfg.omega_jitter)
     return W, sigma, {k: np.asarray(v) for k, v in hist.items()}
 
@@ -120,6 +120,7 @@ def fit_ssdca(
     every cfg.rounds passes to mirror Algorithm 1's schedule.
     """
     loss = get_loss(cfg.loss)
+    learns = omega_reg.resolve_regularizer(cfg).learns
     m, n_max, d = data.m, data.n_max, data.d
     passes = passes if passes is not None else cfg.outer_iters * cfg.rounds
     alpha = jnp.zeros((m, n_max), data.x.dtype)
@@ -169,7 +170,7 @@ def fit_ssdca(
             hist["dual"].append(float(dd))
             hist["primal"].append(float(pp))
             hist["gap"].append(float(pp - dd))
-        if cfg.learn_omega and (t + 1) % cfg.rounds == 0:
+        if learns and (t + 1) % cfg.rounds == 0:
             W = (B @ sigma).T / cfg.lam
             sigma, omega = omega_mod.omega_step(W, cfg.omega_jitter)
             one_pass = make_pass(sigma)

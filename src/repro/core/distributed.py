@@ -57,27 +57,6 @@ class MeshAxes:
     pod: Optional[str] = None  # intra-task samples
 
 
-@dataclasses.dataclass(frozen=True)
-class DistributedOptions:
-    """Mesh-engine knobs, split out of the legacy kitchen-sink config.
-
-    The estimator facade passes these alongside the core ``DMTRLConfig``;
-    the deprecated ``fit_distributed`` keeps reading the equivalent legacy
-    config fields when no options object is given.
-    """
-
-    axes: MeshAxes = MeshAxes()
-    dist_block_hoisted: bool = False  # hoisted block-Gram distributed round
-    gram_bf16: bool = False  # bf16 MXU inputs in the distributed gram build
-
-    def merge_into(self, cfg: DMTRLConfig) -> DMTRLConfig:
-        return dataclasses.replace(
-            cfg,
-            dist_block_hoisted=self.dist_block_hoisted,
-            gram_bf16=self.gram_bf16,
-        )
-
-
 def _axis_size(mesh: Mesh, name: Optional[str]) -> int:
     return mesh.shape[name] if name is not None else 1
 
@@ -206,6 +185,10 @@ def make_local_solve(
     sum of n. The coordinates and keys are the padded layout's, so the
     iterates are too, one for one. Only backends with ``packed`` run there:
     the Pallas ones run over a task's padded rows.
+
+    Every regime builds its solver with one ``backend.make`` call: with a
+    ``model`` axis the backend psums its d-contractions over it, and the
+    Pallas backends, which cannot, refuse it.
     """
     if sigma_input not in ("rows", "diag"):
         raise ValueError(f"sigma_input must be 'rows' or 'diag', got {sigma_input!r}")
@@ -222,16 +205,12 @@ def make_local_solve(
             'solver="block_gram"'
         )
     H = backend.round_local_iters(cfg.local_iters or n_loc, cfg.block_size)
-    # with a sharded feature dim the full-Gram form is used regardless of the
-    # configured backend: ONE batched (q, G) build + psum over 'model' for
-    # ALL local tasks (2 collectives per round vs 3 per block), then a
-    # collective-free vmapped scalar recursion — identical iterates to
-    # naive/block (tested). Per-task backends can't psum their own
-    # d-contractions from inside a Pallas kernel (docs/DESIGN.md §5).
-    use_gram = axes.model is not None
+    # with a model axis the backend psums its d-contractions over it; the
+    # Pallas makers refuse axis_name (docs/DESIGN.md §5)
     packed_kw = {"n_cap": n_max} if packed else {}
-    solver = None if use_gram else backend.make(
-        loss, rho, cfg.lam, H, block=cfg.block_size, axis_name=None, **packed_kw
+    solver = backend.make(
+        loss, rho, cfg.lam, H, block=cfg.block_size, axis_name=axes.model,
+        **packed_kw,
     )
 
     def local_solve(x, y, n, alpha, W_read, sigma_rows, key):
@@ -248,91 +227,7 @@ def make_local_solve(
             sigma_ii = jnp.take_along_axis(sigma_rows, tids[:, None], axis=1)[:, 0]
         # local valid sample count in this pod's contiguous slice
         n_local = jnp.clip(n - pi * n_loc, 0, n_loc).astype(jnp.int32)
-        if use_gram:
-            from .sdca import (
-                add_block, sample_coords, sdca_block_solve, sdca_gram_solve,
-            )
-
-            coords = jax.vmap(
-                lambda nn, kk: sample_coords(kk, H, nn, x.shape[1])
-            )(n_local, keys)  # (m_loc, H)
-            if cfg.dist_block_hoisted:
-                # docs/DESIGN.md §7: hoisted BLOCK-Gram — collective bytes per
-                # round are 3*H*B per task (vs H^2 for the full Gram);
-                # identical iterates to the block/naive modes.
-                nf = jnp.maximum(n, 1).astype(x.dtype)
-                kap = rho * sigma_ii / (cfg.lam * nf)
-                Bsz = cfg.block_size
-                nb = H // Bsz
-                cb_all = coords.reshape(x.shape[0], nb, Bsz)
-
-                def blk(carry, bi):
-                    dalpha, r = carry
-                    cb = cb_all[:, bi]  # (m_loc, B)
-                    Xb = jnp.take_along_axis(x, cb[:, :, None], axis=1)
-                    Xg = Xb.astype(
-                        jnp.bfloat16 if cfg.gram_bf16 else Xb.dtype
-                    )
-                    q = jax.lax.psum(
-                        jnp.einsum("mbd,md->mb", Xb, W_read), axes.model
-                    )
-                    xr = jax.lax.psum(
-                        jnp.einsum("mbd,md->mb", Xb, r), axes.model
-                    )
-                    G = jax.lax.psum(
-                        jnp.einsum(
-                            "mbd,mkd->mbk",
-                            Xg,
-                            Xg,
-                            preferred_element_type=jnp.float32,
-                        ),
-                        axes.model,
-                    )
-                    take = lambda v: jnp.take_along_axis(v, cb, axis=1)
-                    at0 = take(alpha) + take(dalpha)  # (m_loc, B)
-                    deltas = jax.vmap(lambda *a: sdca_block_solve(*a, loss))(
-                        G, q, xr, at0, take(y), cb, kap
-                    )
-                    dalpha = jax.vmap(add_block)(dalpha, cb, deltas)
-                    r = r + jnp.einsum("mbd,mb->md", Xb, deltas)
-                    return (dalpha, r), None
-
-                dalpha0 = jnp.zeros_like(alpha)
-                r0 = jnp.zeros_like(W_read) + x[:, 0] * 0
-                (dalpha, r), _ = jax.lax.scan(
-                    blk, (dalpha0, r0), jnp.arange(nb)
-                )
-            else:
-                Xs = jnp.take_along_axis(
-                    x, coords[:, :, None], axis=1
-                )  # (m_loc, H, d_loc)
-                # docs/DESIGN.md §7: stream the sampled rows in bf16 for the MXU
-                # contractions (fp32 accumulation); halves the dominant X-read
-                # traffic. Validated against the fp32 path in tests.
-                gemm_dtype = jnp.bfloat16 if cfg.gram_bf16 else Xs.dtype
-                Xg = Xs.astype(gemm_dtype)
-                q = jax.lax.psum(
-                    jnp.einsum(
-                        "mhd,md->mh",
-                        Xg,
-                        W_read.astype(gemm_dtype),
-                        preferred_element_type=jnp.float32,
-                    ),
-                    axes.model,
-                )
-                G = jax.lax.psum(
-                    jnp.einsum(
-                        "mhd,mkd->mhk", Xg, Xg, preferred_element_type=jnp.float32
-                    ),
-                    axes.model,
-                )
-                dalpha, deltas = jax.vmap(
-                    lambda Gm, qm, am, ym, cm, nn, sm: sdca_gram_solve(
-                        Gm, qm, am, ym, cm, nn, sm, rho, cfg.lam, loss
-                    )
-                )(G, q, alpha, y, coords, n_local, sigma_ii)
-                r = jnp.einsum("mhd,mh->md", Xs, deltas)
-        elif packed:  # x, y and alpha are shared by the tasks' solves
+        if packed:  # x, y and alpha are shared by the tasks' solves
             offsets = jnp.cumsum(n) - n
             dalpha, r = jax.vmap(solver, in_axes=(None, None, None, 0, 0, 0, 0, 0))(
                 x, y, alpha, W_read, n, sigma_ii, keys, offsets
@@ -473,22 +368,17 @@ def server_reduce(cfg: DMTRLConfig, axes: MeshAxes, sigma_rows, db):
 
 def round_shard_map(cfg: DMTRLConfig, axes: MeshAxes, body, mesh, in_specs, out_specs):
     """shard_map a round/tick body, disabling the replication check only
-    when the configured backend actually traces a pallas_call into the body
-    (jax has no replication rule for pallas_call; with a model axis the
-    gram path is used instead, so the check stays on)."""
-    pallas = get_backend(cfg.solver).uses_pallas and axes.model is None
+    when the configured backend traces a pallas_call into the body (jax
+    has no replication rule for pallas_call)."""
     return jax.shard_map(
         body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_vma=not pallas,
+        check_vma=not get_backend(cfg.solver).uses_pallas,
     )
 
 
 # the DMTRLConfig fields a round program's trace reads: with the mesh, the
 # axes, the shapes and the builders below, its static key
-_ROUND_FIELDS = (
-    "loss", "lam", "eta", "solver", "block_size", "local_iters",
-    "dist_block_hoisted", "gram_bf16",
-)
+_ROUND_FIELDS = ("loss", "lam", "eta", "solver", "block_size", "local_iters")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -648,7 +538,6 @@ def fit_distributed(
     axes: Optional[MeshAxes] = None,
     track: bool = True,
     *,
-    options: Optional[DistributedOptions] = None,
     init: Optional[WarmStart] = None,
     regularizer=None,
 ):
@@ -656,16 +545,12 @@ def fit_distributed(
     pod axis is absent (tested); with pods the CoCoA block structure is finer
     (m*pods blocks) so iterates differ but convergence is preserved.
 
-    ``options`` overrides the legacy per-engine config fields; ``init``
-    warm-starts from raw-shaped (alpha, sigma, omega); ``regularizer``
-    overrides the Omega family member (see core.omega_regularizers).
+    ``init`` warm-starts from raw-shaped (alpha, sigma, omega);
+    ``regularizer`` overrides the Omega family member (see
+    core.omega_regularizers).
     """
     if axes is None:
-        # an explicit axes argument wins; otherwise the options object may
-        # carry the mesh mapping (the estimator path resolves it the same way)
-        axes = options.axes if options is not None else MeshAxes()
-    if options is not None:
-        cfg = options.merge_into(cfg)
+        axes = MeshAxes()
     reg = omega_reg.resolve_regularizer(cfg, regularizer, m=raw.m)
     # driver spans (obs): shard / rho / round / objectives / omega_step /
     # result, on the profiler's clock while a profiler session records.

@@ -12,10 +12,10 @@ contract. The registered engines wrap the existing drivers bit-identically
   reference    single-process Algorithm 1 (core/dmtrl.py:fit); the
                semantic oracle. No mesh, no options.
   distributed  parameter-server W-step on a JAX mesh
-               (core/distributed.py:fit_distributed); DistributedOptions.
+               (core/distributed.py:fit_distributed); mesh and axes, no
+               options.
   async        bounded-staleness SSP engine
-               (core/async_dmtrl.py:fit_async); AsyncOptions (+ the
-               distributed knobs via DistributedOptions merged upstream).
+               (core/async_dmtrl.py:fit_async); AsyncOptions.
 """
 from __future__ import annotations
 
@@ -25,11 +25,7 @@ from typing import Any, Callable, Dict, List, Optional
 import numpy as np
 
 from .async_dmtrl import AsyncOptions, fit_async as _fit_async
-from .distributed import (
-    DistributedOptions,
-    MeshAxes,
-    fit_distributed as _fit_distributed,
-)
+from .distributed import MeshAxes, fit_distributed as _fit_distributed
 from .dmtrl import DMTRLConfig, WarmStart, fit as _fit_reference
 from .mtl_data import MTLData
 from .sigma_view import SigmaView, maybe_dense
@@ -144,7 +140,8 @@ def _make_mesh_run(
     fit_fn: Callable, engine_name: str
 ) -> Callable[..., EngineResult]:
     """One adapter for both mesh engines: resolve a default mesh, forward
-    to the driver (which resolves axes itself), unpad, pack EngineResult."""
+    to the driver (which resolves axes itself, and takes ``options`` when
+    its engine has an options class), unpad, pack EngineResult."""
 
     def run(
         cfg: DMTRLConfig,
@@ -158,12 +155,12 @@ def _make_mesh_run(
         track: bool = True,
     ) -> EngineResult:
         if mesh is None:
-            ax = axes or getattr(options, "axes", None) or MeshAxes()
-            mesh = _default_mesh(ax)
+            mesh = _default_mesh(axes or MeshAxes())
+        kw = {} if options is None else {"options": options}
         with span("engine_run", cat="driver", engine=engine_name):
             W, sigma, state, hist = fit_fn(
-                cfg, data, mesh, axes, track=track,
-                options=options, init=init, regularizer=regularizer,
+                cfg, data, mesh, axes, track=track, init=init,
+                regularizer=regularizer, **kw,
             )
         alpha, omega = _unpad_state(state, data)
         sigma_view = None
@@ -197,7 +194,7 @@ register_engine(
         description="parameter-server W-step sharded over a JAX mesh "
         "(data/model/pod axes); bulk-synchronous rounds",
         needs_mesh=True,
-        options_cls=DistributedOptions,
+        options_cls=None,
         run=_run_distributed,
     )
 )

@@ -1,8 +1,6 @@
 """DMTRLEstimator — the engine-agnostic training/serving facade.
 
-One object covers what used to take three divergent entry points
-(``fit`` / ``fit_distributed`` / ``fit_async``) plus hand-rolled predict
-code:
+One object trains on any engine and serves what it learned:
 
     est = DMTRLEstimator(engine="async", mesh=mesh,
                          async_options=AsyncOptions(tau=2),
@@ -13,10 +11,9 @@ code:
 Design (docs/DESIGN.md §8):
   * engines resolve through ``core.engines`` (same registry pattern as the
     solver backends) — the estimator is bit-identical to the engine's
-    deprecated direct entry point (parity-tested);
-  * per-engine knobs arrive as typed ``DistributedOptions`` /
-    ``AsyncOptions`` objects; passing them as core config fields raises so
-    async-only knobs can no longer leak into the reference engine;
+    own function (parity-tested);
+  * the config holds the algorithm's fields alone; the mesh engines take
+    ``mesh`` / ``axes`` and the async engine its ``AsyncOptions``;
   * the Omega regularizer is a named family member
     (``core.omega_regularizers``) — the paper's trace_constraint by
     default;
@@ -37,7 +34,7 @@ import numpy as np
 
 from . import dual as dual_mod
 from .async_dmtrl import AsyncOptions
-from .distributed import DistributedOptions, MeshAxes
+from .distributed import MeshAxes
 from .dmtrl import DMTRLConfig, WarmStart
 from .engines import Engine, EngineResult, get_engine
 from .losses import get_loss
@@ -45,21 +42,6 @@ from .mtl_data import MTLData, PackedMTLData
 from .omega_regularizers import OmegaRegularizer, get_regularizer
 from .sigma_view import SigmaView
 
-# engine-specific legacy config fields the facade refuses as core params
-_ASYNC_FIELDS = frozenset(
-    {
-        "tau",
-        "tau_max",
-        "async_delays",
-        "omega_delay",
-        "transport",
-        "n_workers",
-        "staleness_budget",
-        "topology",
-        "codec",
-    }
-)
-_DIST_FIELDS = frozenset({"dist_block_hoisted", "gram_bf16"})
 _CONFIG_FIELDS = frozenset(f.name for f in dataclasses.fields(DMTRLConfig))
 
 # history keys that index time and must continue, not restart, across
@@ -80,11 +62,10 @@ class DMTRLEstimator:
     ----------
     engine : "reference" | "distributed" | "async" (core.engines registry)
     config : optional pre-built core DMTRLConfig; core field kwargs
-        (``loss=``, ``lam=``, ``rounds=`` ...) override it. Engine-specific
-        legacy fields (``tau``, ``dist_block_hoisted``, ...) are rejected
-        here — pass ``async_options=AsyncOptions(...)`` /
-        ``distributed=DistributedOptions(...)`` instead.
+        (``loss=``, ``lam=``, ``rounds=`` ...) override it.
     mesh / axes : mesh engines only; a 1-device mesh is built when omitted.
+    async_options : the async engine's ``AsyncOptions`` (``tau``, the
+        transport, ...).
     regularizer : Omega family member name or OmegaRegularizer instance
         (core.omega_regularizers); ``regularizer_params`` configure named
         members (e.g. ``{"adjacency": A}`` for graph_laplacian).
@@ -103,7 +84,6 @@ class DMTRLEstimator:
         config: Optional[DMTRLConfig] = None,
         mesh=None,
         axes: Optional[MeshAxes] = None,
-        distributed: Optional[DistributedOptions] = None,
         async_options: Optional[AsyncOptions] = None,
         regularizer: Union[str, OmegaRegularizer, None] = None,
         regularizer_params: Optional[dict] = None,
@@ -111,18 +91,12 @@ class DMTRLEstimator:
     ):
         self.engine: Engine = get_engine(engine)
 
-        leaked = sorted((_ASYNC_FIELDS | _DIST_FIELDS) & params.keys())
-        if leaked:
-            raise ValueError(
-                f"{leaked} are per-engine options, not core config fields; "
-                "pass async_options=AsyncOptions(...) / "
-                "distributed=DistributedOptions(...) instead"
-            )
         unknown = sorted(params.keys() - _CONFIG_FIELDS)
         if unknown:
             raise ValueError(
                 f"unknown config fields {unknown}; valid core fields: "
-                f"{sorted(_CONFIG_FIELDS - _ASYNC_FIELDS - _DIST_FIELDS)}"
+                f"{sorted(_CONFIG_FIELDS)} (the async engine's knobs go in "
+                "async_options=AsyncOptions(...))"
             )
         cfg = config if config is not None else DMTRLConfig()
         if params:
@@ -135,23 +109,10 @@ class DMTRLEstimator:
                     'engine="reference" is single-process; mesh/axes need '
                     'engine="distributed" or "async"'
                 )
-            if distributed is not None or async_options is not None:
-                raise ValueError(
-                    'engine="reference" takes no DistributedOptions/'
-                    "AsyncOptions — the facade keeps per-engine knobs out "
-                    "of the reference path"
-                )
-        if async_options is not None and self.engine.name != "async":
+        if async_options is not None and self.engine.options_cls is not AsyncOptions:
             raise ValueError(
                 f'AsyncOptions need engine="async", got engine='
                 f"{self.engine.name!r}"
-            )
-        if distributed is not None and not isinstance(
-            distributed, DistributedOptions
-        ):
-            raise TypeError(
-                f"distributed= takes DistributedOptions, got "
-                f"{type(distributed).__name__}"
             )
         if async_options is not None and not isinstance(
             async_options, AsyncOptions
@@ -162,15 +123,10 @@ class DMTRLEstimator:
             )
         self.mesh = mesh
         self.axes = axes
-        self.distributed_options = distributed
         self.async_options = async_options
 
         if regularizer is None:
-            # legacy learn_omega=False maps to the identity_stl member, same
-            # as the deprecated entry points (resolve_regularizer precedence)
-            regularizer = (
-                cfg.omega_regularizer if cfg.learn_omega else "identity_stl"
-            )
+            regularizer = cfg.omega_regularizer
         if isinstance(regularizer, str):
             regularizer = get_regularizer(
                 regularizer, **(regularizer_params or {})
@@ -193,30 +149,11 @@ class DMTRLEstimator:
         self._model_refs: list = []
 
     # -- training -----------------------------------------------------------
-    def _engine_kwargs(self) -> dict:
-        options = None
-        if self.engine.options_cls is AsyncOptions:
-            options = self.async_options
-        elif self.engine.options_cls is DistributedOptions:
-            options = self.distributed_options
-        cfg = self.config
-        if (
-            self.engine.name == "async"
-            and self.distributed_options is not None
-        ):
-            # async reuses the distributed round internals; its gram knobs
-            # ride in through the merged config
-            cfg = self.distributed_options.merge_into(cfg)
-        axes = self.axes
-        if axes is None and self.distributed_options is not None:
-            axes = self.distributed_options.axes
-        return dict(cfg=cfg, mesh=self.mesh, axes=axes, options=options)
-
     def _run(self, data: MTLData, init: Optional[WarmStart], track: bool):
-        kw = self._engine_kwargs()
-        cfg = kw.pop("cfg")
         res: EngineResult = self.engine.run(
-            cfg, data, regularizer=self.regularizer, init=init, track=track, **kw
+            self.config, data, mesh=self.mesh, axes=self.axes,
+            options=self.async_options, regularizer=self.regularizer,
+            init=init, track=track,
         )
         self._install(res, continued=init is not None)
         return res

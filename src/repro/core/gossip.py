@@ -15,7 +15,7 @@ stack cannot tell the difference:
     the cross-transport parity tests, and the serving fleet's model
     subscribers work unchanged.
   * Topologies: ``ring`` / ``torus`` / ``complete`` / an explicit
-    adjacency matrix (``cfg.topology``); the mixing matrix is the
+    adjacency matrix (``AsyncOptions.topology``); the mixing matrix is the
     Metropolis–Hastings weighting, symmetric and doubly stochastic by
     construction, with ``spectral_gap`` introspection (the 1 - |lambda_2|
     quantity that rates how fast consensus contracts).
@@ -222,11 +222,12 @@ class GossipTransport(ThreadedTransport):
 
     name = "gossip"
 
-    def setup(self, cfg, raw, *, mesh, axes, reg, init, track):
+    def setup(self, cfg, raw, *, mesh, axes, reg, init, track, options):
         super().setup(
-            cfg, raw, mesh=mesh, axes=axes, reg=reg, init=init, track=track
+            cfg, raw, mesh=mesh, axes=axes, reg=reg, init=init, track=track,
+            options=options,
         )
-        topology = getattr(cfg, "topology", "complete")
+        topology = options.topology
         self.adjacency = build_adjacency(topology, self.G)
         self.M = mixing_matrix(self.adjacency)
         self.spectral_gap = spectral_gap(self.M)

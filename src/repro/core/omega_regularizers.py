@@ -23,7 +23,7 @@ Registered members:
                     arXiv:1802.03830): Omega = coupling * L + eps I from a
                     known task graph; Sigma never updates.
   identity_stl      Sigma fixed at I/m — independent ridge-regularized
-                    tasks; subsumes ``DMTRLConfig.learn_omega=False``.
+                    tasks (the STL baseline, core/baselines.py).
   frobenius_shrunk  trace_constraint update shrunk toward I/m:
                     Sigma = (1-g) Sigma_ZY + g I/m (trace stays 1). A
                     shared-representation-flavoured member in the spirit of
@@ -186,9 +186,7 @@ def resolve_regularizer(
     """Resolve the regularizer an engine should run under.
 
     Precedence: an explicit ``regularizer`` argument (instance or name) >
-    legacy ``cfg.learn_omega=False`` (maps to identity_stl) >
-    ``cfg.omega_regularizer``. ``cfg`` is duck-typed: only
-    ``learn_omega`` / ``omega_regularizer`` are read. When the caller
+    ``cfg.omega_regularizer``, the only field of ``cfg`` read. When the caller
     knows the task count it passes ``m`` so a dense member requested at
     scale gets a one-time structured-member warning.
     """
@@ -201,16 +199,9 @@ def resolve_regularizer(
                 f"got {type(regularizer).__name__}; parameterized members "
                 "are built via get_regularizer(name, **params)"
             )
-        if not getattr(cfg, "learn_omega", True) and regularizer.learns:
-            raise ValueError(
-                f"learn_omega=False conflicts with the learning regularizer "
-                f"{regularizer.name!r}; drop learn_omega or pick a fixed member"
-            )
         _warn_if_dense_at_scale(regularizer, m, dense_warn_threshold)
         return regularizer
-    if not getattr(cfg, "learn_omega", True):
-        return get_regularizer("identity_stl")
-    name = getattr(cfg, "omega_regularizer", "trace_constraint")
+    name = cfg.omega_regularizer
     try:
         reg = get_regularizer(name)
         _warn_if_dense_at_scale(reg, m, dense_warn_threshold)
@@ -484,8 +475,8 @@ register_regularizer(
 register_regularizer(
     "identity_stl",
     _identity_stl,
-    "fixed Sigma = I/m: independent ridge-regularized tasks (subsumes "
-    "learn_omega=False)",
+    "fixed Sigma = I/m: independent ridge-regularized tasks (the STL "
+    "baseline)",
 )
 register_regularizer(
     "graph_laplacian",

@@ -28,7 +28,7 @@ math here (and the Pallas kernels in repro.kernels.sdca) behind one
 ``solve(...)`` contract.
 
 Sharding: when ``axis_name`` is given (feature dim d sharded over a mesh
-axis), the d-contractions are psum'ed. naive then needs 2 collectives per
+axis), the d-contractions are psum'ed. naive then needs 3 collectives per
 coordinate; block_gram needs 3 per block — this is the communication
 argument for the block form (docs/DESIGN.md §7).
 """
@@ -163,76 +163,6 @@ def add_block(dalpha: Array, cb: Array, deltas: Array) -> Array:
     same = cb[:, None] == cb[None, :]
     total = jnp.sum(jnp.where(same, deltas[None, :], 0.0), axis=1)
     return dalpha.at[cb].set(dalpha[cb] + total)
-
-
-def sdca_gram_solve(
-    G: Array,  # (H, H) full Gram of sampled rows (already psum'ed)
-    q: Array,  # (H,)   X_H @ w (already psum'ed)
-    alpha_i: Array,
-    y: Array,
-    coords: Array,
-    n_i: Array,
-    sigma_ii: Array,
-    rho: float,
-    lam: float,
-    loss: Loss,
-) -> Tuple[Array, Array]:
-    """The collective-free scalar recursion of full-Gram SDCA.
-
-    Returns (dalpha, deltas); r = X_H^T deltas is computed by the caller on
-    its local feature shard."""
-    H = coords.shape[0]
-    nf = jnp.maximum(n_i.astype(q.dtype), 1.0)
-    kappa = rho * sigma_ii / (lam * nf)
-
-    def body(k, carry):
-        dalpha, deltas = carry
-        corr = jnp.dot(G[k], deltas)  # deltas[k:] still zero
-        c = q[k] + kappa * corr
-        a = kappa * G[k, k]
-        j = coords[k]
-        atilde = alpha_i[j] + dalpha[j]
-        delta = loss.sdca_delta(atilde, c, a, y[j])
-        return dalpha.at[j].add(delta), deltas.at[k].set(delta)
-
-    dalpha0 = jnp.zeros_like(alpha_i) + q[0] * 0.0
-    deltas0 = q * 0.0
-    return jax.lax.fori_loop(0, H, body, (dalpha0, deltas0))
-
-
-def local_sdca_gram(
-    x: Array,
-    y: Array,
-    alpha_i: Array,
-    w_i: Array,
-    n_i: Array,
-    sigma_ii: Array,
-    coords: Array,  # (H,)
-    rho: float,
-    lam: float,
-    loss: Loss,
-    axis_name: Optional[str] = None,
-) -> Tuple[Array, Array]:
-    """Full-Gram Local SDCA: same iterate sequence as naive/block, but ALL
-    d-contractions are hoisted out of the sequential loop:
-
-        q = psum(X_H @ w),  G = psum(X_H X_H^T)     (2 collectives TOTAL)
-        H scalar steps entirely on the H x H Gram   (no collectives)
-        r = X_H^T deltas                             (local per shard)
-
-    vs 3 collectives PER BLOCK for the block mode — this is the
-    communication-optimal form for a model-sharded feature dim and the one
-    the distributed path uses (docs/DESIGN.md §7)."""
-    Xs = x[coords]  # (H, d_shard)
-    q = _psum(Xs @ w_i, axis_name)  # (H,)
-    G = _psum(
-        jax.lax.dot_general(Xs, Xs, (((1,), (1,)), ((), ()))), axis_name
-    )  # (H, H)
-    dalpha, deltas = sdca_gram_solve(
-        G, q, alpha_i, y, coords, n_i, sigma_ii, rho, lam, loss
-    )
-    r = Xs.T @ deltas  # local shard of X^T dalpha
-    return dalpha, r
 
 
 def sdca_block_solve(

@@ -18,7 +18,7 @@ Registered backends:
 
   naive        literal Algorithm 2, one coordinate per step (oracle).
   block_gram   jnp block-Gram form (docs/DESIGN.md §4): same iterates,
-               MXU-shaped; supports a sharded feature dim via psum.
+               MXU-shaped.
   pallas_block per-block Pallas kernel: one pallas_call per H-block,
                ``w``/``r`` re-streamed from HBM every block.
   pallas_round fused Pallas round kernel: ALL H/B blocks in one
@@ -38,6 +38,11 @@ packed rows, reading sample j of the task at row ``offset + j``, with the
 same coordinate draws. The Pallas backends run their kernels over a
 task's padded rows and refuse packed data
 (core/distributed.py:make_local_solve).
+
+With a sharded feature dim (a ``model`` mesh axis) ``make`` gets that
+axis as ``axis_name``: ``naive`` and ``block_gram`` psum their
+d-contractions over it, and the Pallas backends, whose kernels compute
+their own, refuse it.
 """
 from __future__ import annotations
 
@@ -69,8 +74,6 @@ class SolverBackend:
     description: str
     # H must be rounded up to a multiple of the block size
     block_aligned: bool
-    # can psum its d-contractions over a sharded feature axis
-    supports_sharded_features: bool
     # make(loss, rho, lam, H, block=..., axis_name=...) -> Solver
     make: Callable[..., Solver]
     # pallas_call launches per local round for given (H, block)
@@ -253,7 +256,6 @@ register_backend(
         description="literal Algorithm 2: one coordinate per step, d-dim "
         "inner product + axpy each (reference semantics)",
         block_aligned=False,
-        supports_sharded_features=True,
         make=_make_naive,
         packed=True,
     )
@@ -264,7 +266,6 @@ register_backend(
         description="jnp block-Gram form: three matmuls per B-block plus a "
         "B-step scalar recursion on the Gram block; same iterates as naive",
         block_aligned=True,
-        supports_sharded_features=True,
         make=_make_block_gram,
         packed=True,
     )
@@ -275,7 +276,6 @@ register_backend(
         description="per-block Pallas kernel: one pallas_call per H-block, "
         "w/r re-streamed from HBM each block",
         block_aligned=True,
-        supports_sharded_features=False,
         make=_make_pallas_block,
         pallas_calls=lambda H, block: H // block,
         uses_pallas=True,
@@ -287,7 +287,6 @@ register_backend(
         description="fused Pallas round kernel: all H/B blocks in one "
         "pallas_call, w/r VMEM-resident, on-device coordinate sampling",
         block_aligned=True,
-        supports_sharded_features=False,
         make=_make_pallas_round,
         pallas_calls=lambda H, block: 1,
         uses_pallas=True,

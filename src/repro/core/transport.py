@@ -26,7 +26,7 @@ Worker-facing primitives — the portable object:
     observed staleness (server commits between snapshot and apply) and lag
     (rounds ahead of the slowest worker at start).
   * ``install_sigma(sigma, omega, defer=...)``  Omega-step result install;
-    with ``defer=True`` it lands only after ``cfg.omega_delay`` commits of
+    with ``defer=True`` it lands only after ``opts.omega_delay`` commits of
     the next W-step (overlapped Omega-step), else immediately.
 
 Driver-facing lifecycle: ``setup`` / ``run_w_step`` / ``w_true`` /
@@ -66,7 +66,7 @@ Members
                   mixing at round boundaries; on a complete graph it
                   matches the threaded server.
 
-Wire formats (``core/wire.py``): ``cfg.codec`` picks the snapshot/commit
+Wire formats (``core/wire.py``): ``opts.codec`` picks the snapshot/commit
 codec (``none`` / ``bf16`` / ``int8`` + error feedback) for the host
 transports and the gossip exchanges; the multiprocess frames carry a
 version byte so protocol skew raises ``TransportProtocolError``.
@@ -106,7 +106,6 @@ from .distributed import (
     install_initial_state,
     make_local_solve,
     pad_sigma_any,
-    pad_sigma_blocks,
     pad_to_multiple,
     round_in_specs,
     round_out_specs,
@@ -334,9 +333,9 @@ def _adapt_tau(
     return tau
 
 
-def _worker_delays(cfg: DMTRLConfig, n_workers: int) -> tuple:
+def _worker_delays(opts, n_workers: int) -> tuple:
     delays = (
-        (1,) * n_workers if cfg.async_delays is None else cfg.async_delays
+        (1,) * n_workers if opts.async_delays is None else opts.async_delays
     )
     delays = tuple(int(v) for v in delays)
     if len(delays) != n_workers:
@@ -543,7 +542,10 @@ class Transport:
             self.unsubscribe(cb)
 
     # -- driver lifecycle ---------------------------------------------------
-    def setup(self, cfg, raw, *, mesh, axes, reg, init, track) -> None:
+    def setup(self, cfg, raw, *, mesh, axes, reg, init, track, options) -> None:
+        """Build the run's state; ``options`` is the run's
+        ``async_dmtrl.AsyncOptions``, the home of every knob the transport
+        reads besides the algorithm's ``cfg``."""
         raise NotImplementedError
 
     def run_w_step(self, p: int, rho: float, outer_key: Array) -> None:
@@ -614,8 +616,8 @@ class Transport:
                 self.tau,
                 self.gate_blocks,
                 conv_mod.staleness_summary(win),
-                cfg.tau_max,
-                cfg.staleness_budget,
+                self.opts.tau_max,
+                self.opts.staleness_budget,
             )
             self.gate_blocks = 0
             self.refused = set()  # a still-blocked worker re-counts
@@ -647,36 +649,37 @@ class SimulatedTransport(Transport):
     name = "simulated"
     needs_mesh = True
 
-    def setup(self, cfg, raw, *, mesh, axes, reg, init, track):
+    def setup(self, cfg, raw, *, mesh, axes, reg, init, track, options):
         if mesh is None:
             raise ValueError("the simulated transport needs a mesh")
-        codec = getattr(cfg, "codec", "none")
+        codec = options.codec
         if codec != "none":
             raise ValueError(
                 "transport='simulated' is the bit-parity anchor and has no "
                 f"wire; codec={codec!r} needs a host transport "
                 "('threaded' / 'multiprocess' / 'gossip')"
             )
-        topology = getattr(cfg, "topology", "complete")
+        topology = options.topology
         if not (isinstance(topology, str) and topology == "complete"):
             raise ValueError(
                 "topology= is a gossip-transport option; transport="
                 "'simulated' has no neighbor graph (use transport='gossip')"
             )
         G = _axis_size(mesh, axes.data)
-        if cfg.n_workers is not None and cfg.n_workers != G:
+        if options.n_workers is not None and options.n_workers != G:
             raise ValueError(
                 f"transport='simulated' derives its workers from the mesh "
-                f"data axis (= {G}); n_workers={cfg.n_workers} conflicts"
+                f"data axis (= {G}); n_workers={options.n_workers} conflicts"
             )
-        self.cfg, self.raw, self.mesh, self.axes = cfg, raw, mesh, axes
+        self.cfg, self.opts = cfg, options
+        self.raw, self.mesh, self.axes = raw, mesh, axes
         self.reg, self.track = reg, track
         data, m, d = shard_mtl_data(raw, mesh, axes)
         self.data, self.m, self.d = data, m, d
         self.state = init_state(data, mesh, axes, m, d)
         self.G = G
         self.m_loc = m // G
-        self.delays = _worker_delays(cfg, G)
+        self.delays = _worker_delays(options, G)
         self.n_pods = _axis_size(mesh, axes.pod)
         self.R = cfg.rounds
         self._sr = NamedSharding(mesh, P(axes.data, None))
@@ -706,8 +709,8 @@ class SimulatedTransport(Transport):
         self._clock = 0  # global simulated time, accumulated across W-steps
         self.pending = None  # (sigma, omega) awaiting overlap installation
         # tau="auto": start bulk-synchronous, adapt once per G-commit window
-        self.tau_auto = cfg.tau == "auto"
-        self.tau = 0 if self.tau_auto else cfg.tau
+        self.tau_auto = options.tau == "auto"
+        self.tau = 0 if self.tau_auto else options.tau
         self.adapt_window = G
         self.gate_blocks = 0  # refusal EPISODES this window (a worker
         #   entering the blocked state counts once until it unblocks or the
@@ -805,7 +808,7 @@ class SimulatedTransport(Transport):
             )
 
     def _maybe_install(self, worker=None):
-        if self.pending is not None and self.commits_outer >= self.cfg.omega_delay:
+        if self.pending is not None and self.commits_outer >= self.opts.omega_delay:
             self._install_worker = worker
             try:
                 self._install(*self.pending)
@@ -971,7 +974,7 @@ class _HostServerTransport(Transport):
 
     needs_mesh = False
 
-    def setup(self, cfg, raw, *, mesh, axes, reg, init, track):
+    def setup(self, cfg, raw, *, mesh, axes, reg, init, track, options):
         axes = axes or MeshAxes()
         if mesh is not None and (
             _axis_size(mesh, axes.model) > 1 or _axis_size(mesh, axes.pod) > 1
@@ -980,16 +983,17 @@ class _HostServerTransport(Transport):
                 f"transport={self.name!r} shards tasks over workers only; "
                 "model/pod mesh axes need transport='simulated'"
             )
-        G = cfg.n_workers
+        G = options.n_workers
         if G is None:
             G = _axis_size(mesh, axes.data) if mesh is not None else 1
-        self.cfg, self.raw, self.reg, self.track = cfg, raw, reg, track
+        self.cfg, self.opts = cfg, options
+        self.raw, self.reg, self.track = raw, reg, track
         self.G = G
         self.m = pad_to_multiple(raw.m, G)
         self.m_loc = self.m // G
         self.data = raw.pad_tasks(self.m)
-        self.delays = _worker_delays(cfg, G)
-        self.pace = 0.0 if cfg.async_delays is None else PACE_SECONDS
+        self.delays = _worker_delays(options, G)
+        self.pace = 0.0 if options.async_delays is None else PACE_SECONDS
         self.R = cfg.rounds
         data, dtype = self.data, self.data.x.dtype
         objectives, w_from_alpha = make_data_fns(cfg, data)
@@ -1031,8 +1035,8 @@ class _HostServerTransport(Transport):
         self.commits_total = 0
         self.commits_outer = 0
         self.pending = None
-        self.tau_auto = cfg.tau == "auto"
-        self.tau = 0 if self.tau_auto else cfg.tau
+        self.tau_auto = options.tau == "auto"
+        self.tau = 0 if self.tau_auto else options.tau
         self.adapt_window = G
         self.gate_blocks = 0
         self.gate_refusals_total = 0
@@ -1048,7 +1052,7 @@ class _HostServerTransport(Transport):
         self._t0 = time.monotonic()
         self.p = 0
         # --- wire codec (core/wire.py) ---------------------------------
-        topology = getattr(cfg, "topology", "complete")
+        topology = options.topology
         if self.name in ("threaded", "multiprocess") and not (
             isinstance(topology, str) and topology == "complete"
         ):
@@ -1056,7 +1060,7 @@ class _HostServerTransport(Transport):
                 f"topology= is a gossip-transport option; transport="
                 f"{self.name!r} is a star topology (use transport='gossip')"
             )
-        self.codec: Codec = get_codec(getattr(cfg, "codec", "none"))
+        self.codec: Codec = get_codec(options.codec)
         self._commit_ef = ErrorFeedback(self.codec)
         self._alpha_cache: Dict[int, np.ndarray] = {}
         self.wire_stats = new_wire_stats(codec=self.codec.name)
@@ -1190,7 +1194,7 @@ class _HostServerTransport(Transport):
             self._notify_model(self.W[: self.raw.m, : self.raw.d], sigma_raw)
 
     def _maybe_install(self, worker=None):
-        if self.pending is not None and self.commits_outer >= self.cfg.omega_delay:
+        if self.pending is not None and self.commits_outer >= self.opts.omega_delay:
             self._install_worker = worker
             try:
                 self._install(*self.pending)
@@ -1456,8 +1460,11 @@ class MultiprocessTransport(_HostServerTransport):
             )
         super().__init__()
 
-    def setup(self, cfg, raw, *, mesh, axes, reg, init, track):
-        super().setup(cfg, raw, mesh=mesh, axes=axes, reg=reg, init=init, track=track)
+    def setup(self, cfg, raw, *, mesh, axes, reg, init, track, options):
+        super().setup(
+            cfg, raw, mesh=mesh, axes=axes, reg=reg, init=init, track=track,
+            options=options,
+        )
         self._listener: Optional[socket.socket] = None
         self._procs: List[subprocess.Popen] = []
         self._conns: Dict[int, socket.socket] = {}
@@ -1514,6 +1521,7 @@ class MultiprocessTransport(_HostServerTransport):
                     "init",
                     dict(
                         cfg=self.cfg,
+                        codec=self.codec.name,
                         x=np.asarray(self.data.x[rows]),
                         y=np.asarray(self.data.y[rows]),
                         n=np.asarray(self.data.n[rows]),
@@ -1669,7 +1677,7 @@ def _mp_worker_main():  # pragma: no cover - runs in worker subprocesses
         n = jnp.asarray(init["n"])
         tids = jnp.asarray(init["tids"])
         R, sleep_s = init["R"], init["sleep_s"]
-        codec = get_codec(getattr(cfg, "codec", "none"))
+        codec = get_codec(init["codec"])
         commit_ef = ErrorFeedback(codec)
         # worker-side alpha mirror under lossy codecs: alpha ships once,
         # then the worker replays its own exact eta*dalpha f32 adds — the

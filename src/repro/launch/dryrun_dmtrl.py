@@ -31,7 +31,7 @@ SDS = jax.ShapeDtypeStruct
 
 
 def run(mesh_name: str, m: int, n_max: int, d: int, out_dir: str,
-        H: int = 512, block: int = 128, bf16: bool = False, tag: str = "",
+        H: int = 512, block: int = 128, tag: str = "",
         x_dtype=jnp.float32) -> dict:
     multi = mesh_name == "multi"
     mesh = make_production_mesh(multi_pod=multi)
@@ -40,8 +40,7 @@ def run(mesh_name: str, m: int, n_max: int, d: int, out_dir: str,
     )
     cfg = DMTRLConfig(
         loss="hinge", lam=1e-4, local_iters=H, solver="block_gram",
-        block_size=block, gram_bf16=bf16,
-        dist_block_hoisted=os.environ.get("DMTRL_BLOCK_HOISTED", "0") == "1",
+        block_size=block,
     )
     rho = 4.0  # representative learned-Sigma value (Lemma 10 scale)
     round_fn = make_distributed_round(cfg, mesh, axes, m, n_max, d, rho)
@@ -105,7 +104,6 @@ def main():
     ap.add_argument("--n-max", type=int, default=2048)
     ap.add_argument("--d", type=int, default=8192)
     ap.add_argument("--out", default="results/dryrun")
-    ap.add_argument("--bf16", action="store_true")
     ap.add_argument("--x-bf16", action="store_true")
     ap.add_argument("--tag", default="")
     ap.add_argument("--H", type=int, default=512)
@@ -113,7 +111,7 @@ def main():
     meshes = ["single", "multi"] if args.mesh == "both" else [args.mesh]
     for mn in meshes:
         run(mn, args.m, args.n_max, args.d, args.out, H=args.H,
-            bf16=args.bf16, tag=args.tag,
+            tag=args.tag,
             x_dtype=jnp.bfloat16 if args.x_bf16 else jnp.float32)
 
 

@@ -28,7 +28,7 @@ INT_KEYS = (
     "tau_trace",
 )
 
-# name -> (devices, problem kwargs, config kwargs)
+# name -> (devices, problem kwargs, DMTRLConfig + AsyncOptions kwargs)
 CASES = {
     "g1_tau2_omega1": (
         1,
@@ -69,15 +69,18 @@ _RUNNER = textwrap.dedent(
     sys.path.insert(0, {repo!r} + "/src")
     from repro.core import DMTRLConfig, MeshAxes
     from repro.launch.mesh import make_mesh
-    from repro.core.async_dmtrl import fit_async
+    from repro.core.async_dmtrl import AsyncOptions, fit_async
     from repro.data.synthetic import synthetic
     prob = {prob!r}
     cfg_kw = {cfg!r}
-    cfg_kw["async_delays"] = tuple(cfg_kw["async_delays"])
+    # the flat record splits into the algorithm's config and the options
+    opt_kw = {{k: cfg_kw.pop(k) for k in ("tau", "omega_delay") if k in cfg_kw}}
+    opt_kw["async_delays"] = tuple(cfg_kw.pop("async_delays"))
     sp = synthetic(1, **prob)
     mesh = make_mesh(({devices},), ("data",))
     _, _, _, hist = fit_async(
-        DMTRLConfig(**cfg_kw), sp.train, mesh, MeshAxes(data="data")
+        DMTRLConfig(**cfg_kw), sp.train, mesh, MeshAxes(data="data"),
+        options=AsyncOptions(**opt_kw),
     )
     out = {{k: np.asarray(hist[k]).astype(int).tolist() for k in {keys!r}}}
     print("GOLDEN" + json.dumps(out))

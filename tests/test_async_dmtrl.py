@@ -20,8 +20,10 @@ import textwrap
 import numpy as np
 import pytest
 
-from repro.core import DMTRLConfig, MeshAxes, fit_async, fit_distributed
 from repro.core import convergence as cv
+from repro.core.async_dmtrl import AsyncOptions, fit_async
+from repro.core.distributed import MeshAxes, fit_distributed
+from repro.core.dmtrl import DMTRLConfig
 from repro.data.synthetic import synthetic
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -64,7 +66,9 @@ _STRAGGLER_SUBPROC = textwrap.dedent(
     import json, sys
     import jax, numpy as np
     sys.path.insert(0, {repo!r} + "/src")
-    from repro.core import DMTRLConfig, MeshAxes, fit_async, fit_distributed
+    from repro.core.async_dmtrl import AsyncOptions, fit_async
+    from repro.core.distributed import MeshAxes, fit_distributed
+    from repro.core.dmtrl import DMTRLConfig
     from repro.launch.mesh import make_mesh
     from repro.data.synthetic import synthetic
 
@@ -77,8 +81,9 @@ _STRAGGLER_SUBPROC = textwrap.dedent(
     out = dict(sync_gap=float(h_sync["gap"][-1]))
     mask = np.asarray(sp.train.mask)
     for tau in (1, 4):
-        cfg = DMTRLConfig(**base, tau=tau, async_delays=(1, 1, 1, 3))
-        _, _, st, h = fit_async(cfg, sp.train, mesh, ax)
+        opts = AsyncOptions(tau=tau, async_delays=(1, 1, 1, 3))
+        _, _, st, h = fit_async(DMTRLConfig(**base), sp.train, mesh, ax,
+                                options=opts)
         out[f"tau{{tau}}_gap"] = float(h["gap"][-1])
         out[f"tau{{tau}}_stal"] = int(h["w_staleness"].max())
         out[f"tau{{tau}}_lag"] = int(h["w_lag"].max())
@@ -90,9 +95,10 @@ _STRAGGLER_SUBPROC = textwrap.dedent(
             all(np.any(alpha[i][mask[i] == 1.0] != 0.0)
                 for i in range(sp.train.m))
         )
-    cfg_auto = DMTRLConfig(**dict(base, outer_iters=2), tau="auto",
-                           async_delays=(1, 1, 1, 3))
-    _, _, _, h_auto = fit_async(cfg_auto, sp.train, mesh, ax)
+    cfg_auto = DMTRLConfig(**dict(base, outer_iters=2))
+    opts_auto = AsyncOptions(tau="auto", async_delays=(1, 1, 1, 3))
+    _, _, _, h_auto = fit_async(cfg_auto, sp.train, mesh, ax,
+                                options=opts_auto)
     out["auto_gap"] = float(h_auto["gap"][-1])
     out["auto_tau_max"] = int(h_auto["tau_trace"].max())
     out["auto_tau_start"] = int(h_auto["tau_trace"][0])
@@ -142,10 +148,11 @@ def test_stale_snapshots_never_mix_tasks(one_device_mesh):
     for tau in (0, 2):
         cfg = DMTRLConfig(
             loss="squared", lam=1e-3, outer_iters=1, rounds=5, local_iters=32,
-            solver="block_gram", block_size=32, seed=7, tau=tau,
+            solver="block_gram", block_size=32, seed=7,
         )
         _, _, state, _ = fit_async(
-            cfg, data, one_device_mesh, MeshAxes(data="data")
+            cfg, data, one_device_mesh, MeshAxes(data="data"),
+            options=AsyncOptions(tau=tau),
         )
         alpha = np.asarray(state.alpha)[: data.m]
         mask = np.asarray(data.mask)
@@ -182,14 +189,12 @@ def test_tau_auto_one_device_matches_sync(
 ):
     """A single worker can never be gated, so tau="auto" must stay at 0 and
     reproduce the synchronous engine bit-exactly."""
-    import dataclasses
-
-    cfg = dataclasses.replace(small_cfg, tau="auto")
     W1, s1, st1, _ = fit_distributed(
         small_cfg, small_problem.train, one_device_mesh, MeshAxes(data="data")
     )
     W2, s2, st2, h2 = fit_async(
-        cfg, small_problem.train, one_device_mesh, MeshAxes(data="data")
+        small_cfg, small_problem.train, one_device_mesh, MeshAxes(data="data"),
+        options=AsyncOptions(tau="auto"),
     )
     assert np.array_equal(W1, W2)
     assert np.array_equal(np.asarray(st1.alpha), np.asarray(st2.alpha))
@@ -201,10 +206,11 @@ def test_omega_overlap_converges(small_problem, one_device_mesh):
     still reduce the duality gap and end with a valid trace-1 Sigma."""
     cfg = DMTRLConfig(
         loss="hinge", lam=1e-3, outer_iters=3, rounds=4, local_iters=32,
-        solver="block_gram", block_size=32, seed=0, tau=1, omega_delay=2,
+        solver="block_gram", block_size=32, seed=0,
     )
     W, sigma, _, hist = fit_async(
-        cfg, small_problem.train, one_device_mesh, MeshAxes(data="data")
+        cfg, small_problem.train, one_device_mesh, MeshAxes(data="data"),
+        options=AsyncOptions(tau=1, omega_delay=2),
     )
     assert np.trace(sigma) == pytest.approx(1.0, abs=1e-4)
     assert hist["gap"][-1] < hist["gap"][0]
@@ -227,10 +233,11 @@ def test_omega_delay_exceeding_round_budget_still_installs(
     must land at the next barrier, never be silently dropped."""
     cfg = DMTRLConfig(
         loss="hinge", lam=1e-3, outer_iters=2, rounds=3, local_iters=32,
-        solver="block_gram", block_size=32, seed=0, omega_delay=50,
+        solver="block_gram", block_size=32, seed=0,
     )
     _, sigma, _, _ = fit_async(
-        cfg, small_problem.train, one_device_mesh, MeshAxes(data="data")
+        cfg, small_problem.train, one_device_mesh, MeshAxes(data="data"),
+        options=AsyncOptions(omega_delay=50),
     )
     m = small_problem.train.m
     # still learned: not the I/m init the run started from
@@ -238,35 +245,21 @@ def test_omega_delay_exceeding_round_budget_still_installs(
     assert np.trace(sigma) == pytest.approx(1.0, abs=1e-4)
 
 
-def test_bad_config_rejected(small_problem, one_device_mesh):
-    ax = MeshAxes(data="data")
-    with pytest.raises(ValueError, match="tau"):
-        fit_async(
-            DMTRLConfig(tau=-1), small_problem.train, one_device_mesh, ax
-        )
-    # only "auto" is a valid non-int staleness bound
-    for bad in ("adaptive", None, 1.5):
+def test_bad_config_rejected(small_problem, small_cfg, one_device_mesh):
+    # only ints >= 0 and "auto" are staleness bounds
+    for bad in (-1, "adaptive", None, 1.5):
         with pytest.raises(ValueError, match="tau"):
-            fit_async(
-                DMTRLConfig(tau=bad), small_problem.train,
-                one_device_mesh, ax,
-            )
-    with pytest.raises(ValueError, match="async_delays"):
-        fit_async(
-            DMTRLConfig(async_delays=(1, 2)), small_problem.train,
-            one_device_mesh, ax,
-        )
-    # empty tuple must hit the length check, not fall back to all-ones
-    with pytest.raises(ValueError, match="async_delays"):
-        fit_async(
-            DMTRLConfig(async_delays=()), small_problem.train,
-            one_device_mesh, ax,
-        )
+            AsyncOptions(tau=bad)
     with pytest.raises(ValueError, match="omega_delay"):
-        fit_async(
-            DMTRLConfig(omega_delay=-2), small_problem.train,
-            one_device_mesh, ax,
-        )
+        AsyncOptions(omega_delay=-2)
+    # the delay schedule's length is checked against the mesh's workers at
+    # setup; an empty tuple must hit that check, not fall back to all-ones
+    for delays in ((1, 2), ()):
+        with pytest.raises(ValueError, match="async_delays"):
+            fit_async(
+                small_cfg, small_problem.train, one_device_mesh,
+                MeshAxes(data="data"), options=AsyncOptions(async_delays=delays),
+            )
 
 
 _SUBPROC = textwrap.dedent(
@@ -276,7 +269,9 @@ _SUBPROC = textwrap.dedent(
     import json, sys
     import jax, numpy as np
     sys.path.insert(0, {repo!r} + "/src")
-    from repro.core import DMTRLConfig, MeshAxes, fit_async, fit_distributed
+    from repro.core.async_dmtrl import AsyncOptions, fit_async
+    from repro.core.distributed import MeshAxes, fit_distributed
+    from repro.core.dmtrl import DMTRLConfig
     from repro.launch.mesh import make_mesh
     from repro.data.synthetic import synthetic
 
@@ -294,8 +289,8 @@ _SUBPROC = textwrap.dedent(
                                             np.asarray(st2.alpha))),
         sync_gap=float(h1["gap"][-1]),
     )
-    cfg4 = DMTRLConfig(**base, tau=4, async_delays=(1, 1, 1, 1, 1, 1, 1, 3))
-    W4, s4, st4, h4 = fit_async(cfg4, sp.train, mesh, ax)
+    opts4 = AsyncOptions(tau=4, async_delays=(1, 1, 1, 1, 1, 1, 1, 3))
+    W4, s4, st4, h4 = fit_async(cfg, sp.train, mesh, ax, options=opts4)
     out["tau4_gap"] = float(h4["gap"][-1])
     out["tau4_max_staleness"] = int(h4["w_staleness"].max())
     print(json.dumps(out))

@@ -14,7 +14,8 @@ import textwrap
 import numpy as np
 import pytest
 
-from repro.core import MeshAxes, fit, fit_distributed
+from repro.core.distributed import MeshAxes, fit_distributed
+from repro.core.dmtrl import fit
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -218,7 +219,8 @@ _SUBPROC = textwrap.dedent(
     import json, sys
     import jax, numpy as np
     sys.path.insert(0, {repo!r} + "/src")
-    from repro.core import DMTRLConfig, MeshAxes, fit, fit_distributed
+    from repro.core.dmtrl import DMTRLConfig, fit
+    from repro.core.distributed import MeshAxes, fit_distributed
     from repro.launch.mesh import make_mesh
     from repro.data.synthetic import synthetic
 
@@ -282,24 +284,13 @@ def test_data_plus_model_axes_exact():
     assert r["serr"] < 5e-5, r
 
 
-@pytest.mark.slow
-def test_model_axis_hoisted_block_gram_exact():
-    """the hoisted block-Gram distributed round (dist_block_hoisted) must
-    produce the same iterates as the reference — guards the refactor of the
-    round body into local-solve/server-reduce pieces."""
-    r = _run_subproc(
-        "squared", "(4, 2)", '("data", "model")',
-        'dict(data="data", model="model")',
-        extra='dict(dist_block_hoisted=True)',
-    )
-    assert r["werr"] < 5e-4, r
-    assert r["serr"] < 5e-5, r
-
-
-def test_model_axis_hoisted_block_gram_equals_naive_on_one_device(small_problem):
-    """The hoisted block-Gram round (a ``model`` axis, here of size 1) gives
-    naive's dual variables: 128 draws a round in blocks of 64 from tasks of
-    about 40 samples, so most blocks draw a coordinate several times."""
+@pytest.mark.parametrize("solver", ["naive", "block_gram"])
+def test_model_axis_round_equals_naive_on_one_device(small_problem, solver):
+    """With a ``model`` axis (here of size 1) the round runs the named
+    backend with its d-contractions psum'ed over it, and gives naive's
+    dual variables on a data-only mesh: 128 draws a round in blocks of 64
+    from tasks of about 40 samples, so most blocks draw a coordinate
+    several times."""
     import jax
 
     from repro.core import DMTRLConfig
@@ -310,7 +301,7 @@ def test_model_axis_hoisted_block_gram_equals_naive_on_one_device(small_problem)
     runs = (
         (DMTRLConfig(solver="naive", **base), make_mesh((1,), ("data",)), MeshAxes(data="data")),
         (
-            DMTRLConfig(solver="block_gram", dist_block_hoisted=True, **base),
+            DMTRLConfig(solver=solver, **base),
             make_mesh((1, 1), ("data", "model")),
             MeshAxes(data="data", model="model"),
         ),
@@ -325,11 +316,27 @@ def test_model_axis_hoisted_block_gram_equals_naive_on_one_device(small_problem)
             key = jax.random.PRNGKey(7 + t)
             alpha, W = rf(data.x, data.y, data.mask, data.n, alpha, W, st.sigma, key)
         out.append((np.asarray(alpha), np.asarray(W)))
-    (a_naive, w_naive), (a_hoist, w_hoist) = out
+    (a_naive, w_naive), (a_model, w_model) = out
     assert int(np.max(small_problem.train.n)) < 64
-    np.testing.assert_allclose(a_hoist, a_naive, atol=2e-5)
-    np.testing.assert_allclose(w_hoist, w_naive, atol=2e-5)
+    np.testing.assert_allclose(a_model, a_naive, atol=2e-5)
+    np.testing.assert_allclose(w_model, w_naive, atol=2e-5)
     assert np.abs(a_naive).max() > 1e-3  # the rounds moved
+
+
+def test_model_axis_refuses_pallas_backend(small_problem, small_cfg):
+    """A Pallas kernel computes its own d-contractions and cannot psum them
+    over a ``model`` axis: the engine raises and names block_gram instead
+    of running another form of the round."""
+    from repro.core import DMTRLEstimator
+    from repro.launch.mesh import make_mesh
+
+    est = DMTRLEstimator(
+        engine="distributed", mesh=make_mesh((1, 1), ("data", "model")),
+        axes=MeshAxes(data="data", model="model"), config=small_cfg,
+        solver="pallas_round",
+    )
+    with pytest.raises(ValueError, match="block_gram"):
+        est.fit(small_problem.train)
 
 
 @pytest.mark.slow

@@ -3,7 +3,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import DMTRLConfig, fit
+from repro.core.dmtrl import DMTRLConfig, fit
 from repro.core import dual as dm
 from repro.core import omega as om
 from repro.core.baselines import fit_centralized_mtrl, fit_ssdca, fit_stl
@@ -78,7 +78,7 @@ def test_ssdca_converges_to_same_dual(splits):
     data = synthetic(1, m=4, d=24, n_train_avg=60, n_test_avg=20, seed=3).train
     cfg = DMTRLConfig(
         loss="hinge", lam=1e-2, outer_iters=1, rounds=25, local_iters=128,
-        learn_omega=False, seed=0,
+        omega_regularizer="identity_stl", seed=0,
     )
     res = fit(cfg, data)
     _, _, hist = fit_ssdca(cfg, data, passes=25)

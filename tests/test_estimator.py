@@ -1,8 +1,8 @@
 """DMTRLEstimator facade: engine-registry parity, options, warm start.
 
 Parity anchors:
-  * estimator(engine=E) must be BIT-identical to the deprecated direct
-    entry point of E (the adapters only normalize signatures/returns);
+  * estimator(engine=E) must be BIT-identical to E's own function (the
+    adapters only normalize signatures/returns);
   * through the facade, distributed and async(tau=0) stay bit-identical
     (the PR-1 anchor), and reference matches the mesh engines to the same
     float-op-ordering tolerance the direct APIs are tested at;
@@ -21,7 +21,6 @@ import pytest
 
 from repro.core import (
     AsyncOptions,
-    DistributedOptions,
     DMTRLConfig,
     DMTRLEstimator,
     MeshAxes,
@@ -44,7 +43,7 @@ def test_engine_registry_contents():
     assert {"reference", "distributed", "async"} <= names
     assert get_engine("reference").needs_mesh is False
     assert get_engine("async").options_cls is AsyncOptions
-    assert get_engine("distributed").options_cls is DistributedOptions
+    assert get_engine("distributed").options_cls is None
 
 
 def test_unknown_engine_lists_choices():
@@ -55,7 +54,7 @@ def test_unknown_engine_lists_choices():
 
 
 # ---------------------------------------------------------------------------
-# facade <-> deprecated entry point bit parity
+# facade <-> engine function bit parity
 # ---------------------------------------------------------------------------
 def test_reference_engine_bit_parity(small_problem, small_cfg):
     res = fit(small_cfg, small_problem.train)
@@ -168,10 +167,20 @@ def test_cross_engine_parity_eight_devices():
 # config split / option validation
 # ---------------------------------------------------------------------------
 def test_async_knobs_rejected_as_core_params():
+    """The config holds the algorithm's fields alone; the async engine's
+    knobs live in AsyncOptions and are unknown config fields."""
+    assert [f.name for f in dataclasses.fields(DMTRLConfig)] == [
+        "loss", "lam", "eta", "outer_iters", "rounds", "local_iters",
+        "solver", "block_size", "rho_mode", "rho_fixed", "omega_jitter",
+        "omega_regularizer", "seed", "track_every",
+    ]
+    knobs = [f.name for f in dataclasses.fields(AsyncOptions)]
+    assert len(knobs) == 9
+    for name in knobs:
+        with pytest.raises(ValueError, match="unknown config fields"):
+            DMTRLEstimator(engine="async", **{name: 1})
     with pytest.raises(ValueError, match="AsyncOptions"):
         DMTRLEstimator(engine="async", tau=3)
-    with pytest.raises(ValueError, match="DistributedOptions"):
-        DMTRLEstimator(engine="distributed", dist_block_hoisted=True)
 
 
 def test_unknown_config_field_rejected():
@@ -183,7 +192,7 @@ def test_reference_engine_rejects_mesh_and_options(one_device_mesh):
     with pytest.raises(ValueError, match="single-process"):
         DMTRLEstimator(engine="reference", mesh=one_device_mesh)
     with pytest.raises(ValueError, match="reference"):
-        DMTRLEstimator(engine="reference", distributed=DistributedOptions())
+        DMTRLEstimator(engine="reference", async_options=AsyncOptions())
     with pytest.raises(ValueError, match='engine="async"'):
         DMTRLEstimator(engine="distributed", async_options=AsyncOptions())
 
@@ -196,22 +205,16 @@ def test_async_options_eager_validation():
         AsyncOptions(omega_delay=-1)
     with pytest.raises(ValueError, match="async_delays"):
         AsyncOptions(async_delays=(1, 0))
-    AsyncOptions(tau="auto", async_delays=(1, 2))  # valid forms
-
-
-def test_config_tau_eager_validation():
-    with pytest.raises(ValueError, match="tau"):
-        DMTRLConfig(tau="fast")
-    with pytest.raises(ValueError, match="tau"):
-        DMTRLConfig(tau=-1)
-    assert DMTRLConfig(tau="auto").tau == "auto"
+    # valid forms
+    assert AsyncOptions(tau="auto", async_delays=(1, 2)).tau == "auto"
 
 
 def test_async_options_reach_the_engine(small_problem, small_cfg, one_device_mesh):
-    """AsyncOptions must override the legacy config fields bit-identically."""
-    legacy = dataclasses.replace(small_cfg, omega_delay=1, tau=0)
+    """The estimator hands its AsyncOptions to fit_async unchanged: the
+    two routes give bit-identical results."""
     W1, s1, _, _ = fit_async(
-        legacy, small_problem.train, one_device_mesh, MeshAxes(data="data")
+        small_cfg, small_problem.train, one_device_mesh, MeshAxes(data="data"),
+        options=AsyncOptions(tau=0, omega_delay=1),
     )
     est = DMTRLEstimator(
         engine="async", config=small_cfg, mesh=one_device_mesh,
@@ -310,11 +313,3 @@ def test_predict_validation(small_problem, small_cfg):
 def test_history_requires_fit(small_cfg):
     with pytest.raises(NotFittedError):
         DMTRLEstimator(engine="reference", config=small_cfg).history
-
-
-def test_deprecated_wrappers_still_importable_and_warn(small_problem, small_cfg):
-    import repro.core as core
-
-    with pytest.warns(DeprecationWarning, match="DMTRLEstimator"):
-        res = core.fit(small_cfg, small_problem.train, track=False)
-    assert np.isfinite(np.asarray(res.W)).all()

@@ -21,7 +21,7 @@ Anchors:
 import numpy as np
 import pytest
 
-from repro.core import AsyncOptions, DMTRLConfig, MeshAxes
+from repro.core import AsyncOptions, MeshAxes
 from repro.core import convergence as cv
 from repro.core.async_dmtrl import fit_async
 from repro.core.gossip import build_adjacency, mixing_matrix, spectral_gap
@@ -321,7 +321,7 @@ def test_gossip_wire_stats_monotone_under_codecs(small_problem, small_cfg):
         opts = AsyncOptions(
             transport="gossip", n_workers=4, tau=0, codec=codec
         )
-        cfg = opts.merge_into(small_cfg)
+        cfg = small_cfg
         from repro.core import omega_regularizers as omega_reg
         from repro.core.dmtrl import _rho_value
         import jax
@@ -332,7 +332,7 @@ def test_gossip_wire_stats_monotone_under_codecs(small_problem, small_cfg):
         t = get_transport("gossip").factory()
         t.setup(
             cfg, small_problem.train, mesh=None, axes=MeshAxes(),
-            reg=reg, init=None, track=False,
+            reg=reg, init=None, track=False, options=opts,
         )
         try:
             key = jax.random.PRNGKey(0)

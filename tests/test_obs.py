@@ -386,18 +386,17 @@ def test_transports_share_wire_stats_schema(
     """Every transport's ``wire_stats`` carries the documented key union —
     gossip-only keys (spectral_gap, mix traffic) included, zeroed where a
     transport has nothing to report."""
-    import dataclasses
-
-    from repro.core import MeshAxes
+    from repro.core import AsyncOptions, MeshAxes
     from repro.core.omega_regularizers import resolve_regularizer
     from repro.core.transport import WIRE_STATS_SCHEMA, get_transport
 
-    cfg = dataclasses.replace(
-        small_cfg, transport=name,
+    opts = AsyncOptions(
+        transport=name,
         # simulated derives its worker count from the mesh data axis
         n_workers=None if name == "simulated" else 4,
         **({"topology": "ring"} if name == "gossip" else {}),
     )
+    cfg = small_cfg
     reg = resolve_regularizer(cfg, None, m=small_problem.train.m)
     t = get_transport(name).factory()
     kw = (
@@ -405,7 +404,10 @@ def test_transports_share_wire_stats_schema(
         if name == "simulated"
         else dict(mesh=None, axes=MeshAxes())
     )
-    t.setup(cfg, small_problem.train, reg=reg, init=None, track=False, **kw)
+    t.setup(
+        cfg, small_problem.train, reg=reg, init=None, track=False,
+        options=opts, **kw,
+    )
     try:
         assert set(t.wire_stats) == set(WIRE_STATS_SCHEMA), name
         assert isinstance(t.wire_stats["codec"], str)

@@ -82,14 +82,16 @@ def test_graph_laplacian_validation(small_cfg):
         reg.init(5)
 
 
-def test_identity_stl_equals_learn_omega_false(small_problem, small_cfg):
-    legacy = fit(
-        dataclasses.replace(small_cfg, learn_omega=False), small_problem.train
-    )
+def test_identity_stl_keeps_sigma_fixed_and_matches_stl(small_problem, small_cfg):
+    """identity_stl holds Sigma at I/m through every Omega-step and fits
+    bit for bit what the STL baseline fits."""
+    from repro.core.baselines import fit_stl
+
+    stl = fit_stl(small_cfg, small_problem.train)
     est = _fit_with(small_problem, small_cfg, "identity_stl")
-    assert np.array_equal(est.W_, np.asarray(legacy.W))
-    assert np.array_equal(est.alpha_, np.asarray(legacy.alpha))
-    assert np.array_equal(est.sigma_, np.asarray(legacy.sigma))
+    assert np.array_equal(est.W_, np.asarray(stl.W))
+    assert np.array_equal(est.alpha_, np.asarray(stl.alpha))
+    assert np.array_equal(est.sigma_, np.asarray(stl.sigma))
     m = small_problem.train.m
     np.testing.assert_allclose(est.sigma_, np.eye(m) / m, atol=1e-7)
 
@@ -123,28 +125,14 @@ def test_frobenius_shrunk_interpolates(small_problem, small_cfg):
         get_regularizer("frobenius_shrunk", shrinkage=1.5)
 
 
-def test_facade_learn_omega_false_maps_to_identity_stl(
-    small_problem, small_cfg
-):
-    """Legacy configs with learn_omega=False must fit through the facade
-    (mapped to identity_stl) exactly like the deprecated entry points."""
-    stl_cfg = dataclasses.replace(small_cfg, learn_omega=False)
-    est = DMTRLEstimator(engine="reference", config=stl_cfg).fit(
-        small_problem.train
-    )
-    assert est.regularizer.name == "identity_stl"
-    legacy = fit(stl_cfg, small_problem.train)
-    assert np.array_equal(est.W_, np.asarray(legacy.W))
-    assert np.array_equal(est.sigma_, np.asarray(legacy.sigma))
-
-
 def test_resolve_regularizer_precedence(small_cfg):
     assert resolve_regularizer(small_cfg).name == "trace_constraint"
-    stl_cfg = dataclasses.replace(small_cfg, learn_omega=False)
+    stl_cfg = dataclasses.replace(small_cfg, omega_regularizer="identity_stl")
     assert resolve_regularizer(stl_cfg).name == "identity_stl"
     assert resolve_regularizer(small_cfg, "identity_stl").name == "identity_stl"
-    with pytest.raises(ValueError, match="learn_omega"):
-        resolve_regularizer(stl_cfg, get_regularizer("trace_constraint"))
+    # an explicit member wins over the config's name
+    reg = resolve_regularizer(stl_cfg, get_regularizer("trace_constraint"))
+    assert reg.name == "trace_constraint"
 
 
 def test_family_through_mesh_engines(small_problem, small_cfg, one_device_mesh):

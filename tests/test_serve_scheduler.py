@@ -355,17 +355,16 @@ def test_transport_subscription_feeds_scheduler(small_problem, small_cfg):
     """core/transport.py hook: a Sigma install notifies subscribers with
     raw-size (W, sigma, version) — wired straight into a scheduler, every
     install hot-swaps the served weights."""
-    import dataclasses as dc
-
+    from repro.core import AsyncOptions
     from repro.core.omega_regularizers import resolve_regularizer
     from repro.core.transport import get_transport
 
-    cfg = dc.replace(small_cfg, n_workers=1, transport="threaded")
     transport = get_transport("threaded").factory()
-    reg = resolve_regularizer(cfg, None)
+    reg = resolve_regularizer(small_cfg, None)
     transport.setup(
-        cfg, small_problem.train, mesh=None, axes=None, reg=reg,
+        small_cfg, small_problem.train, mesh=None, axes=None, reg=reg,
         init=None, track=False,
+        options=AsyncOptions(n_workers=1, transport="threaded"),
     )
     try:
         m, d = small_problem.train.m, small_problem.train.d

@@ -89,19 +89,21 @@ def test_bad_transport_knobs_rejected(small_problem, one_device_mesh):
         AsyncOptions(tau=2, staleness_budget=0.5)
     with pytest.raises(KeyError, match="unknown transport"):
         fit_async(
-            DMTRLConfig(transport="smoke-signal"),
+            DMTRLConfig(),
             small_problem.train,
             one_device_mesh,
             MeshAxes(data="data"),
+            options=AsyncOptions(transport="smoke-signal"),
         )
     # simulated derives workers from the mesh; a conflicting n_workers is an
     # error, not a silent override
     with pytest.raises(ValueError, match="n_workers"):
         fit_async(
-            DMTRLConfig(n_workers=2),
+            DMTRLConfig(),
             small_problem.train,
             one_device_mesh,
             MeshAxes(data="data"),
+            options=AsyncOptions(n_workers=2),
         )
 
 
@@ -112,14 +114,22 @@ def _int_history(hist, keys):
     return {k: np.asarray(hist[k]).astype(int).tolist() for k in keys}
 
 
+def _golden_args(rec):
+    """A golden record's flat config, split into the algorithm's
+    DMTRLConfig and the async engine's AsyncOptions."""
+    cfg_kw = dict(rec["config"])
+    opt_kw = {k: cfg_kw.pop(k) for k in ("tau", "omega_delay") if k in cfg_kw}
+    opt_kw["async_delays"] = tuple(cfg_kw.pop("async_delays"))
+    return DMTRLConfig(**cfg_kw), AsyncOptions(**opt_kw)
+
+
 def test_golden_replay_one_device(golden, one_device_mesh):
     rec = golden["g1_tau2_omega1"]
     assert rec["devices"] == 1
-    cfg_kw = dict(rec["config"])
-    cfg_kw["async_delays"] = tuple(cfg_kw["async_delays"])
+    cfg, opts = _golden_args(rec)
     sp = synthetic(1, **rec["problem"])
     _, _, _, hist = fit_async(
-        DMTRLConfig(**cfg_kw), sp.train, one_device_mesh, MeshAxes(data="data")
+        cfg, sp.train, one_device_mesh, MeshAxes(data="data"), options=opts
     )
     assert _int_history(hist, rec["history"].keys()) == rec["history"]
 
@@ -133,14 +143,17 @@ _GOLDEN_SUBPROC = textwrap.dedent(
     sys.path.insert(0, {repo!r} + "/src")
     from repro.core import DMTRLConfig, MeshAxes
     from repro.launch.mesh import make_mesh
-    from repro.core.async_dmtrl import fit_async
+    from repro.core.async_dmtrl import AsyncOptions, fit_async
     from repro.data.synthetic import synthetic
     rec = json.loads({rec!r})
-    cfg_kw = dict(rec["config"]); cfg_kw["async_delays"] = tuple(cfg_kw["async_delays"])
+    cfg_kw = dict(rec["config"])
+    opt_kw = {{k: cfg_kw.pop(k) for k in ("tau", "omega_delay") if k in cfg_kw}}
+    opt_kw["async_delays"] = tuple(cfg_kw.pop("async_delays"))
     sp = synthetic(1, **rec["problem"])
     mesh = make_mesh(({devices},), ("data",))
     _, _, _, hist = fit_async(
-        DMTRLConfig(**cfg_kw), sp.train, mesh, MeshAxes(data="data")
+        DMTRLConfig(**cfg_kw), sp.train, mesh, MeshAxes(data="data"),
+        options=AsyncOptions(**opt_kw),
     )
     out = {{k: np.asarray(hist[k]).astype(int).tolist() for k in rec["history"]}}
     print("REPLAY" + json.dumps(out))
@@ -270,9 +283,9 @@ def test_threaded_warm_start_partial_fit(small_problem):
 
 
 def test_estimator_routes_transport_and_rejects_core_kwarg(small_problem):
-    with pytest.raises(ValueError, match="per-engine options"):
+    with pytest.raises(ValueError, match="unknown config fields.*AsyncOptions"):
         DMTRLEstimator(engine="async", transport="threaded")
-    with pytest.raises(ValueError, match="per-engine options"):
+    with pytest.raises(ValueError, match="unknown config fields"):
         DMTRLEstimator(engine="reference", staleness_budget=1.0)
     est = DMTRLEstimator(
         engine="async",
@@ -307,7 +320,7 @@ def test_simulated_protocol_methods_drive_one_w_step(
     t = get_transport("simulated").factory()
     t.setup(
         cfg, data, mesh=one_device_mesh, axes=MeshAxes(data="data"),
-        reg=reg, init=None, track=False,
+        reg=reg, init=None, track=False, options=AsyncOptions(),
     )
     rho = 1.0  # identity_stl couples nothing; any rho-consistent value —
     # must match what the reference run uses below, so compute it there too
@@ -425,35 +438,27 @@ def test_tau_auto_still_widens_without_budget(small_problem, small_cfg):
 
 
 # ---------------------------------------------------------------------------
-# deprecation hygiene
+# the engine functions are the supported direct surface
 # ---------------------------------------------------------------------------
-def _one_deprecation(fn, *args, **kwargs):
-    with warnings.catch_warnings(record=True) as rec:
-        warnings.simplefilter("always")
-        out = fn(*args, **kwargs)
-    dep = [w for w in rec if issubclass(w.category, DeprecationWarning)]
-    assert len(dep) == 1, [str(w.message) for w in dep]
-    assert "deprecated" in str(dep[0].message)
-    return out
-
-
-def test_deprecated_wrappers_warn_exactly_once(
-    small_problem, small_cfg, one_device_mesh
-):
-    import repro.core as core
+def test_engine_functions_warn_nothing(small_problem, small_cfg, one_device_mesh):
+    """``fit_async``, ``fit_distributed`` and ``fit`` run from their modules
+    with no deprecation warning, and the async knobs reach the simulated
+    transport through AsyncOptions: one worker at a solve time of 2 ticks
+    ends its last round at tick 2 x rounds."""
+    from repro.core.distributed import fit_distributed
 
     ax = MeshAxes(data="data")
-    # raw async_delays/tau kwargs on the legacy config still route through
-    legacy = dataclasses.replace(small_cfg, tau=1, async_delays=(2,))
-    _, _, _, hist = _one_deprecation(
-        core.fit_async, legacy, small_problem.train, one_device_mesh, ax
-    )
+    with warnings.catch_warnings(record=True) as rec:
+        warnings.simplefilter("always")
+        _, _, _, hist = fit_async(
+            small_cfg, small_problem.train, one_device_mesh, ax,
+            options=AsyncOptions(tau=1, async_delays=(2,)),
+        )
+        fit_distributed(small_cfg, small_problem.train, one_device_mesh, ax)
+        fit_reference(small_cfg, small_problem.train)
+    dep = [str(w.message) for w in rec if issubclass(w.category, DeprecationWarning)]
+    assert dep == []
     assert hist["w_tick"][-1] == 2 * small_cfg.outer_iters * small_cfg.rounds
-    _one_deprecation(
-        core.fit_distributed, small_cfg, small_problem.train,
-        one_device_mesh, ax,
-    )
-    _one_deprecation(core.fit, small_cfg, small_problem.train)
 
 
 # ---------------------------------------------------------------------------
@@ -522,12 +527,12 @@ def test_raising_subscriber_is_isolated_and_dropped(
 
     from repro.core.omega_regularizers import resolve_regularizer
 
-    cfg = dataclasses.replace(small_cfg, n_workers=1, transport="threaded")
     transport = get_transport("threaded").factory()
-    reg = resolve_regularizer(cfg, None)
+    reg = resolve_regularizer(small_cfg, None)
     transport.setup(
-        cfg, small_problem.train, mesh=None, axes=None, reg=reg,
+        small_cfg, small_problem.train, mesh=None, axes=None, reg=reg,
         init=None, track=False,
+        options=AsyncOptions(n_workers=1, transport="threaded"),
     )
     try:
         m = small_problem.train.m
@@ -564,12 +569,12 @@ def test_raising_subscriber_does_not_break_the_fit(small_problem, small_cfg):
     import jax
 
     opts = AsyncOptions(transport="threaded", n_workers=2, tau=0)
-    cfg = opts.merge_into(small_cfg)
+    cfg = small_cfg
     reg = omega_reg.resolve_regularizer(cfg, None, m=small_problem.train.m)
     t = get_transport("threaded").factory()
     t.setup(
         cfg, small_problem.train, mesh=None, axes=MeshAxes(), reg=reg,
-        init=None, track=True,
+        init=None, track=True, options=opts,
     )
     try:
         t.subscribe(lambda *a: (_ for _ in ()).throw(RuntimeError("boom")))
@@ -620,11 +625,11 @@ def test_payload_nbytes_codec_accounting(small_problem, small_cfg):
     from repro.core.omega_regularizers import resolve_regularizer
     from repro.core.transport import payload_nbytes
 
-    cfg = dataclasses.replace(small_cfg, n_workers=2, transport="threaded")
     t = get_transport("threaded").factory()
     t.setup(
-        cfg, small_problem.train, mesh=None, axes=None,
-        reg=resolve_regularizer(cfg, None), init=None, track=False,
+        small_cfg, small_problem.train, mesh=None, axes=None,
+        reg=resolve_regularizer(small_cfg, None), init=None, track=False,
+        options=AsyncOptions(n_workers=2, transport="threaded"),
     )
     try:
         snap = t.snapshot(0)
@@ -650,12 +655,12 @@ def test_threaded_wire_stats_alpha_elision(small_problem, small_cfg):
     import jax
 
     opts = AsyncOptions(transport="threaded", n_workers=2, tau=0, codec="int8")
-    cfg = opts.merge_into(small_cfg)
+    cfg = small_cfg
     reg = omega_reg.resolve_regularizer(cfg, None, m=small_problem.train.m)
     t = get_transport("threaded").factory()
     t.setup(
         cfg, small_problem.train, mesh=None, axes=MeshAxes(), reg=reg,
-        init=None, track=False,
+        init=None, track=False, options=opts,
     )
     try:
         key = jax.random.PRNGKey(0)
