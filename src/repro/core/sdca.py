@@ -180,9 +180,15 @@ def sdca_block_solve(
 
     Step k's dual is ``at0[k]`` plus the deltas of earlier steps that drew
     the same coordinate (``deltas[k:]`` are still 0), so only (B,) arrays
-    ride in the loop, as in the Pallas kernel (kernels/sdca)."""
+    ride in the loop, as in the Pallas kernel (kernels/sdca). Step k
+    writes its delta with a select over the block's lanes, not
+    ``deltas.at[k].set``: vmapped over tasks that one-element write lowers
+    to a scatter, whose in-bounds predicate XLA computes at every step."""
     B = cb.shape[0]
     same = (cb[:, None] == cb[None, :]).astype(q.dtype)  # (B, B)
+    # derived from cb so that, vmapped, the lanes carry the task axis too:
+    # the compare then fuses into the select, not a kernel of its own
+    lane = jnp.arange(B) + cb * 0
 
     def body(k, deltas):
         # c_k = q_k + kappa * (x_k^T r + sum_{k'<k} G[k,k'] delta_k')
@@ -191,7 +197,7 @@ def sdca_block_solve(
         a = kappa * G[k, k]
         atilde = at0[k] + jnp.sum(same[k] * deltas)
         delta = loss.sdca_delta(atilde, c, a, yb[k])
-        return deltas.at[k].set(delta)
+        return jnp.where(lane == k, delta, deltas)
 
     # derive from q so the carry carries the same varying-manual-axes
     # type as the inputs under shard_map

@@ -6,6 +6,7 @@ import pytest
 
 from repro.core import dual as dm
 from repro.core import omega as om
+from repro.core import sdca
 from repro.core.dmtrl import DMTRLConfig, make_w_step_round
 from repro.core.losses import get_loss, registered_losses
 from repro.core.sdca import local_sdca_block, local_sdca_naive, sample_coords
@@ -54,12 +55,14 @@ def _dup_args(layout, n_i, loss, key, H=192, n_cap=64, d=30):
     return args, kw
 
 
-# block sizes over the fixture's task 1, then heavy duplicates at B = 64
-_BLOCK_CASES = [pytest.param((b, None, None), id=str(b)) for b in (16, 32, 96)] + [
+# heavy duplicates at B = 64
+_DUP_CASES = [
     pytest.param((64, layout, n_i), id=f"{layout}-n{n_i}")
     for layout in ("padded", "packed")
     for n_i in (3, 17, 60)
 ]
+# block sizes over the fixture's task 1, then the duplicate cases
+_BLOCK_CASES = [pytest.param((b, None, None), id=str(b)) for b in (16, 32, 96)] + _DUP_CASES
 
 
 @pytest.mark.parametrize("loss_name", sorted(registered_losses()))
@@ -102,6 +105,20 @@ def _nested_loop_carries(jaxpr, depth=0):
     return out
 
 
+def _nested_loop_primitives(jaxpr, loops=0):
+    """Names of the primitives inside every scan or while loop nested in
+    another (``loops`` counts the loops around ``jaxpr``)."""
+    names = {eqn.primitive.name for eqn in jaxpr.eqns} if loops >= 2 else set()
+    for eqn in jaxpr.eqns:
+        inner = loops + (eqn.primitive.name in ("scan", "while"))
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    names |= _nested_loop_primitives(sub, inner)
+    return names
+
+
 @pytest.mark.parametrize("tasks", [1, 3])
 @pytest.mark.parametrize("layout", ["padded", "packed"])
 def test_block_recursion_carries_no_sample_axis(layout, tasks):
@@ -121,6 +138,68 @@ def test_block_recursion_carries_no_sample_axis(layout, tasks):
     carries = _nested_loop_carries(jax.make_jaxpr(solve)(*batched).jaxpr)
     assert carries, "no loop nested in the block scan"
     assert all(a.size <= tasks * B for a in carries), [a.str_short() for a in carries]
+
+
+@pytest.mark.parametrize("tasks", [1, 3])
+@pytest.mark.parametrize("layout", ["padded", "packed"])
+def test_block_recursion_has_no_scatter(layout, tasks):
+    """Each recursion step writes its delta with a select over the block's
+    lanes: a scatter there costs an in-bounds predicate at every step."""
+    args, kw = _dup_args(layout, 17, get_loss("hinge"), jax.random.PRNGKey(3), n_cap=200)
+    solve = lambda x, y, a, w, n, s, c: local_sdca_block(
+        x, y, a, w, n, s, c, *args[7:], block=64, **kw
+    )
+    if tasks > 1:  # vmapped over tasks, as the engines run it
+        batched = tuple(jnp.stack([v] * tasks) for v in args[:7])
+        solve = jax.vmap(solve)
+    else:
+        batched = args[:7]
+    found = _nested_loop_primitives(jax.make_jaxpr(solve)(*batched).jaxpr)
+    assert "dot_general" in found, "no recursion nested in the block scan"
+    assert not found & {"scatter", "scatter-add", "scatter_add"}, found
+
+
+def _scatter_block_solve(G, q, xr, at0, yb, cb, kappa, loss):
+    """sdca_block_solve with each step's delta written by a scatter, the
+    form the select replaced: the reference for bit equality."""
+    B = cb.shape[0]
+    same = (cb[:, None] == cb[None, :]).astype(q.dtype)
+
+    def body(k, deltas):
+        corr = jnp.dot(G[k], deltas)
+        c = q[k] + kappa * (xr[k] + corr)
+        a = kappa * G[k, k]
+        atilde = at0[k] + jnp.sum(same[k] * deltas)
+        delta = loss.sdca_delta(atilde, c, a, yb[k])
+        return deltas.at[k].set(delta)
+
+    return jax.lax.fori_loop(0, B, body, q * 0.0)
+
+
+@pytest.mark.parametrize("loss_name", sorted(registered_losses()))
+@pytest.mark.parametrize("block", _DUP_CASES)
+def test_select_write_equals_scatter_write(monkeypatch, loss_name, block):
+    """The select writes exactly what the scatter wrote: three tasks'
+    rounds, vmapped, agree bit for bit in dalpha and r."""
+    block, layout, n_i = block
+    loss = get_loss(loss_name)
+    tasks = [_dup_args(layout, n_i, loss, jax.random.PRNGKey(s)) for s in (11, 12, 13)]
+    kw = tasks[0][1]
+    batched = tuple(jnp.stack(v) for v in zip(*(args[:7] for args, _ in tasks)))
+    # a fresh function per call, so each traces the sdca_block_solve of its time
+    rnd = lambda: jax.jit(
+        jax.vmap(
+            lambda x, y, a, w, n, s, c: local_sdca_block(
+                x, y, a, w, n, s, c, 2.0, 1e-3, loss, block=block, **kw
+            )
+        )
+    )(*batched)
+    da_new, r_new = rnd()
+    monkeypatch.setattr(sdca, "sdca_block_solve", _scatter_block_solve)
+    da_old, r_old = rnd()
+    assert np.abs(np.asarray(da_new)).max() > 1e-3  # the rounds moved
+    np.testing.assert_array_equal(np.asarray(da_new), np.asarray(da_old))
+    np.testing.assert_array_equal(np.asarray(r_new), np.asarray(r_old))
 
 
 @pytest.mark.parametrize("loss_name", ["hinge", "squared", "smoothed_hinge"])
